@@ -889,8 +889,10 @@ def main(argv=None):
     # multi-process children (spawned at module import by --processes):
     # join the jax.distributed job before the first device query; a
     # no-op in single-process runs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.distributed import maybe_initialize
     maybe_initialize()
+    enable_compile_cache()
     if a.block_sweep:
         run_block_sweep(rounds=a.rounds, warmup=a.warmup, out=a.out)
     elif a.multihost:

@@ -877,10 +877,8 @@ class FederatedTrainer:
             loss_fn, algo_cfg.eta_l, variant=self.algo.client_variant,
             optimizer=algo_cfg.local_optimizer, **client_kwargs(self.algo))
         algo, eta_g = self.algo, algo_cfg.eta_g
-        model_sharded = bool(
-            self.mesh is not None and "model" in self.mesh.axis_names
-            and dict(zip(self.mesh.axis_names,
-                         self.mesh.devices.shape))["model"] > 1)
+        partitioned = bool(self.mesh is not None
+                           and self.mesh.devices.size > 1)
         # codec stage (repro.codec, DESIGN.md §13): the wave ENCODES its
         # cohort before the entries hit the arrival heap — in-flight and
         # checkpointed entries carry the quantized wire payload, and the
@@ -953,7 +951,7 @@ class FederatedTrainer:
             if algo.staleness_aware:
                 out = algo.step(server_state, params, deltas, ids, eta_g,
                                 0, client_mask=cm,
-                                model_sharded=model_sharded,
+                                partitioned=partitioned,
                                 staleness_weights=weights,
                                 encoded=encoded)
             else:
@@ -961,7 +959,7 @@ class FederatedTrainer:
                     lambda x: weights.reshape((-1,) + (1,) * (x.ndim - 1))
                     * x.astype(jnp.float32), deltas)
                 out = algo.step(server_state, params, pre, ids, eta_g, 0,
-                                client_mask=cm, model_sharded=model_sharded)
+                                client_mask=cm, partitioned=partitioned)
             new_opt = None
             if sopt is not None:
                 # precondition the POST-projection aggregate: re-step

@@ -27,13 +27,14 @@ deltas are client-stacked pytrees (leading axis k'), client_ids (k',) int32.
 pads the cohort to a multiple of the client axis (DESIGN.md §2): masked
 rows carry dummy clients whose deltas must not perturb the client mean,
 FedExP's extrapolation count, or FedVARP's table — dummy client_ids are
-out of range and are dropped by the scatter.  ``model_sharded`` (bool
-kwarg, default False) declares that delta/param leaves are partitioned
-over a mesh model axis (the two-axis round, §2); rules that only use
-dim-preserving tree reductions can ignore it (GSPMD reduces the partials
-for free), rules with layout-sensitive fast paths (FedDPC's Pallas
-epilogue) must fall back.  Steps accept unknown kwargs (**_) so new
-execution hints never break an algorithm that does not care.
+out of range and are dropped by the scatter.  ``partitioned`` (bool
+kwarg, default False) declares that the round is partitioned by GSPMD
+over a mesh of more than one device (client axis, model axis or both,
+§2); rules that only use dim-preserving tree reductions can ignore it
+(GSPMD reduces the partials for free), rules with fast paths GSPMD
+cannot partition (FedDPC's Pallas epilogue) must fall back.  Steps
+accept unknown kwargs (**_) so new execution hints never break an
+algorithm that does not care.
 
 Algorithms register through ``register_algorithm(name, HyperCls)``: each
 carries a frozen hyperparameter dataclass (``FedDPCHyper(lam=...)``,
@@ -348,12 +349,12 @@ def _build_fedvarp(h):
 @register_algorithm("feddpc", FedDPCHyper)
 def _build_feddpc(h):
     def step(state, params, deltas, client_ids, eta_g, t,
-             client_mask=None, model_sharded=False,
+             client_mask=None, partitioned=False,
              staleness_weights=None, encoded=None, edges=None, **_):
         return feddpc_mod.server_step(state, params, deltas, eta_g, h.lam,
                                       use_kernel=h.use_kernel,
                                       client_mask=client_mask,
-                                      model_sharded=model_sharded,
+                                      partitioned=partitioned,
                                       staleness_weights=staleness_weights,
                                       encoded=encoded, edges=edges)
     return ServerAlgo("feddpc", lambda p, n: feddpc_mod.init_state(p), step,
@@ -434,11 +435,11 @@ def _build_feddpc_m(h):
         return s
 
     def step(state, params, deltas, client_ids, eta_g, t,
-             client_mask=None, model_sharded=False,
+             client_mask=None, partitioned=False,
              staleness_weights=None, edges=None, **_):
         _, new_state, diag = feddpc_mod.server_step(
             {"delta_prev": state["delta_prev"]}, params, deltas, 0.0, lam,
-            client_mask=client_mask, model_sharded=model_sharded,
+            client_mask=client_mask, partitioned=partitioned,
             staleness_weights=staleness_weights, edges=edges)
         delta_t = new_state["delta_prev"]
         m = jax.tree.map(

@@ -30,7 +30,7 @@ def init_state(params: PyTree) -> Dict[str, PyTree]:
 
 def server_step(state: Dict[str, PyTree], params: PyTree, deltas: PyTree,
                 eta_g: float, lam: float = 1.0, use_kernel: bool = False,
-                client_mask=None, model_sharded: bool = False,
+                client_mask=None, partitioned: bool = False,
                 staleness_weights=None, encoded=None, edges=None
                 ) -> Tuple[PyTree, Dict[str, PyTree], Dict[str, jnp.ndarray]]:
     """One FedDPC aggregation.
@@ -46,16 +46,17 @@ def server_step(state: Dict[str, PyTree], params: PyTree, deltas: PyTree,
     unchanged mean-over-k' epilogue (jnp or Pallas) then computes the
     mean over real clients only, with no kernel changes.
 
-    model_sharded=True declares that the delta/param leaves are
-    PARTITIONED over a mesh model axis (the two-axis cohort round,
-    DESIGN.md §2). The reduction-pass scalars need no change: they go
-    through dim-preserving per-leaf sums (projection.tree_vdot), so
-    GSPMD psums the ||Δ||² / <Δ, Δ_prev> partials across the model axis
-    before ``scale``/``coef`` are formed. The Pallas epilogue, however,
-    flattens every leaf (reshaping a partitioned leaf forces an
-    all-gather) and pallas_call does not partition under GSPMD, so
-    ``use_kernel`` falls back to the reference jnp epilogue — which is
-    elementwise on the local shards and exact.
+    partitioned=True declares that the round is PARTITIONED by GSPMD
+    over a mesh of more than one device: the client axis, the model axis
+    of the two-axis cohort round, or both (DESIGN.md §2). The
+    reduction-pass scalars need no change: they go through
+    dim-preserving per-leaf sums (projection.tree_vdot), so GSPMD psums
+    the ||Δ||² / <Δ, Δ_prev> partials across the mesh before
+    ``scale``/``coef`` are formed. A Mosaic kernel, however, cannot be
+    partitioned automatically (the TPU compiler refuses it), and the
+    epilogue's per-leaf flatten would all-gather model-sharded leaves,
+    so ``use_kernel`` falls back to the reference jnp epilogue — which
+    is elementwise on the local shards and exact.
 
     staleness_weights (k',) f32 are the buffered-async discount factors
     (core/async_engine.py, DESIGN.md §11): each buffered delta's weight
@@ -84,11 +85,11 @@ def server_step(state: Dict[str, PyTree], params: PyTree, deltas: PyTree,
     the epilogue routes to the fused dequant→residual→scale→mean grid
     (kernels/feddpc_project.dequant_*), which reads the int8/bf16 stack
     straight from HBM — the full-precision decoded stack never makes a
-    second full-memory pass. Ignored on the model-sharded fallback.
+    second full-memory pass. Ignored on the partitioned fallback.
 
     Returns (new_params, new_state, diagnostics).
     """
-    if model_sharded:
+    if partitioned:
         use_kernel = False
     delta_prev = state["delta_prev"]
 

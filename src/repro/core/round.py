@@ -222,7 +222,7 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
     from the vmapped local training, the FedDPC reduction-pass scalars
     reduce over BOTH axes automatically (dim-preserving per-leaf sums ->
     GSPMD inserts the model-axis psum before the scale is formed), and
-    the server rule sees ``model_sharded=True`` so the Pallas epilogue
+    the server rule sees ``partitioned=True`` so the Pallas epilogue
     falls back to the reference path (its flatten would all-gather the
     shards).
 
@@ -245,9 +245,10 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
     local = client_mod.make_cohort_local_update(
         loss_fn, eta_l, variant=algo.client_variant, optimizer=optimizer,
         **client_kwargs(algo))
-    model_sharded = bool(
-        mesh is not None and model_axis in mesh.axis_names
-        and dict(zip(mesh.axis_names, mesh.devices.shape))[model_axis] > 1)
+    # any mesh of more than one device partitions the round under GSPMD,
+    # which cannot partition a Mosaic kernel: the server rule then takes
+    # its jnp epilogue (DESIGN.md §2)
+    partitioned = bool(mesh is not None and mesh.devices.size > 1)
     # codec stage (repro/codec, DESIGN.md §13): only LOSSY codecs enter
     # the program — identity is a literal pass-through and compiles to
     # the no-codec round. Error feedback adds one params-shaped f32
@@ -319,7 +320,7 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
         # NOT rewritten rows between decode and aggregation
         new_params, new_state, diag = algo.step(
             server_state, params, deltas, client_ids, eta_g, 0,
-            client_mask=cm, model_sharded=model_sharded,
+            client_mask=cm, partitioned=partitioned,
             encoded=(payload if codec_lossy and not guard else None),
             edges=edges)
         new_opt = None
@@ -394,7 +395,7 @@ def make_fl_round_step(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
             f"exactly {{'delta_prev'}}; {algorithm!r} keeps {sorted(probe)} "
             f"— use make_cohort_round for stateful server rules")
     # mesh/model_axis are forwarded so the raw round still sees
-    # model_sharded=True on a two-axis mesh (the Pallas-fallback contract
+    # partitioned=True on a multi-device mesh (the Pallas-fallback contract
     # holds on this entry point too); jit=False leaves the sharding
     # layout to the external jit below
     cohort = make_cohort_round(loss_fn, algo, eta_l, eta_g, jit=False,

@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the compute hot spots (FedDPC server epilogue,
+flash attention, selective scan). Each ``<name>/ops.py`` entry point
+takes ``interpret=None`` and resolves it with ``resolve_interpret``: the
+compiled Mosaic kernel on a TPU, the Pallas interpreter elsewhere."""
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret`` as given, or — when None — interpret mode exactly
+    when the default backend is not a TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -19,7 +19,19 @@ entire server epilogue is a single HBM pass over (K+2)·d floats instead
 of K separate per-client kernel launches under vmap plus two more full
 passes for the mean and the parameter update.
 
-Validated in interpret mode on CPU against ref.py.
+Layout rules the TPU lowering enforces (and that interpret mode does
+not): every row block is a whole number of the strictest operand's
+(sublane, 128) tile — 8 rows for f32, 16 for bf16, 32 for int8 — so each
+entry point pads M up to whole blocks itself and trims its outputs; the
+per-client / per-arrival scalars (coefs, scales, staleness weights,
+dequant scale/zero, eta) sit whole in SMEM and are read per client
+index; the per-block dot partials are written to SMEM.
+
+Every kernel here is checked against ref.py in interpret mode on CPU,
+AOT-compiled for a described TPU v5e at ResNet-18 widths
+(tests/test_tpu_compile.py). On a v5e chip, chip_smoke.py runs
+``batched_epilogue`` inside the full-width ResNet-18 FedDPC round and
+checks all six against ref.py at that model's largest leaf.
 """
 from __future__ import annotations
 
@@ -28,35 +40,84 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 DEFAULT_ROWS = 512          # (512, 128) f32 block = 256 KiB VMEM per operand
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)     # whole array, scalar reads
+
+
+def sublane(*dtypes) -> int:
+    """Rows of the strictest (sublane, 128) tile among ``dtypes``: 8 for
+    4-byte, 16 for 2-byte, 32 for 1-byte element types."""
+    return max(8, *(32 // jnp.dtype(dt).itemsize for dt in dtypes))
+
+
+def block_rows(m: int, target: int, tile: int) -> int:
+    """Row block for an (M, 128) operand: ``target`` rounded down to a
+    whole number of ``tile`` rows (at least one tile), and never more
+    than M rounded up to one."""
+    rows = max(tile, target // tile * tile)
+    return min(rows, -(-m // tile) * tile)
+
+
+def cohort_rows(k: int) -> int:
+    """Row target when all K clients' blocks are resident at once: the
+    K·rows·128 block shrinks with K so it keeps fitting VMEM."""
+    return max(8, DEFAULT_ROWS // max(1, k))
+
+
+def _pad_rows(x: jnp.ndarray, m_pad: int) -> jnp.ndarray:
+    """Zero-pad the row axis (second to last) of x to m_pad."""
+    pad = m_pad - x.shape[-2]
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[-2] = (0, pad)
+    return jnp.pad(x, widths)
+
+
+def _plan(m: int, target: int, *arrays):
+    """(rows, padded M) for a grid of whole, tile-aligned row blocks."""
+    rows = block_rows(m, target, sublane(*(a.dtype for a in arrays)))
+    return rows, -(-m // rows) * rows
+
+
+def _dots_call(kernel, ncol, d2, p2, rows, interpret):
+    """Shared grid of the reduction-pass kernels: (G, ncol) per-block
+    partials. Each block writes a (1, 1, ncol) SMEM block of a
+    (G, 1, ncol) array — its last two dims span the array's, which the
+    TPU lowering accepts where a (1, ncol) block of (G, ncol) is not."""
+    m = d2.shape[0]
+    rows, mp = _plan(m, rows, d2, p2)
+    grid = (mp // rows,)
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
+                  pl.BlockSpec((rows, LANE), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((1, 1, ncol), lambda i: (i, 0, 0),
+                               memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 1, ncol), jnp.float32),
+        interpret=interpret,
+    )(_pad_rows(d2, mp), _pad_rows(p2, mp))[:, 0]
 
 
 def _reduce_kernel(d_ref, p_ref, out_ref):
     d = d_ref[...].astype(jnp.float32)
     p = p_ref[...].astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(d * p)
-    out_ref[0, 1] = jnp.sum(d * d)
-    out_ref[0, 2] = jnp.sum(p * p)
+    out_ref[0, 0, 0] = jnp.sum(d * p)
+    out_ref[0, 0, 1] = jnp.sum(d * d)
+    out_ref[0, 0, 2] = jnp.sum(p * p)
 
 
 def fused_dots(d2: jnp.ndarray, p2: jnp.ndarray, *, rows: int = DEFAULT_ROWS,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool) -> jnp.ndarray:
     """d2/p2: (M, 128). Returns (G, 3) per-block partials of
-    [<d,p>, <d,d>, <p,p>] — sum over G outside (one tiny reduction)."""
-    m = d2.shape[0]
-    rows = min(rows, m)
-    grid = (pl.cdiv(m, rows),)
-    return pl.pallas_call(
-        _reduce_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-                  pl.BlockSpec((rows, LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 3), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 3), jnp.float32),
-        interpret=interpret,
-    )(d2, p2)
+    [<d,p>, <d,d>, <p,p>] — sum over G outside (one tiny reduction).
+    Zero rows padded onto the last block add nothing to the dots."""
+    return _dots_call(_reduce_kernel, 3, d2, p2, rows, interpret)
 
 
 def _guard_reduce_kernel(d_ref, p_ref, out_ref):
@@ -70,29 +131,33 @@ def _guard_reduce_kernel(d_ref, p_ref, out_ref):
     p = p_ref[...].astype(jnp.float32)
     finite = jnp.isfinite(d)
     df = jnp.where(finite, d, 0.0)
-    out_ref[0, 0] = jnp.sum(df * p)
-    out_ref[0, 1] = jnp.sum(df * df)
-    out_ref[0, 2] = jnp.sum(p * p)
-    out_ref[0, 3] = jnp.sum((~finite).astype(jnp.float32))
+    out_ref[0, 0, 0] = jnp.sum(df * p)
+    out_ref[0, 0, 1] = jnp.sum(df * df)
+    out_ref[0, 0, 2] = jnp.sum(p * p)
+    out_ref[0, 0, 3] = jnp.sum((~finite).astype(jnp.float32))
 
 
 def guard_dots(d2: jnp.ndarray, p2: jnp.ndarray, *, rows: int = DEFAULT_ROWS,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool) -> jnp.ndarray:
     """d2/p2: (M, 128). Returns (G, 4) per-block partials of
     [<d,p>, <d,d>, <p,p>, nonfinite(d)] — ``fused_dots`` with the guard
     column riding the same pass; sum over G outside."""
-    m = d2.shape[0]
-    rows = min(rows, m)
-    grid = (pl.cdiv(m, rows),)
-    return pl.pallas_call(
-        _guard_reduce_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-                  pl.BlockSpec((rows, LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 4), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 4), jnp.float32),
-        interpret=interpret,
-    )(d2, p2)
+    return _dots_call(_guard_reduce_kernel, 4, d2, p2, rows, interpret)
+
+
+def _cohort_mean(d_ref, p, coef_ref, scale_ref, qs_ref=None, qz_ref=None):
+    """mean_j scale_j * (d_j - coef_j * p) over the resident (K, r, 128)
+    block, one client at a time; with qs/qz each d_j is dequantized on
+    the fly as ``q_j * qs_j + qz_j``."""
+    k = d_ref.shape[0]
+
+    def client(j, acc):
+        d = d_ref[j].astype(jnp.float32)
+        if qs_ref is not None:
+            d = d * qs_ref[j] + qz_ref[j]
+        return acc + scale_ref[j] * (d - coef_ref[j] * p)
+
+    return jax.lax.fori_loop(0, k, client, jnp.zeros_like(p)) / k
 
 
 def _batched_epilogue_kernel(coef_ref, scale_ref, eta_ref, d_ref, p_ref,
@@ -105,46 +170,24 @@ def _batched_epilogue_kernel(coef_ref, scale_ref, eta_ref, d_ref, p_ref,
     d_ref block is (K, rows, 128) — all K clients' tile resident at once,
     so the stacked deltas are read exactly ONCE per round.
     """
-    d = d_ref[...].astype(jnp.float32)                    # (K, r, 128)
     p = p_ref[...].astype(jnp.float32)                    # (r, 128)
-    coef = coef_ref[...].astype(jnp.float32)[:, None, None]
-    scale = scale_ref[...].astype(jnp.float32)[:, None, None]
-    dt = jnp.mean(scale * (d - coef * p[None]), axis=0)
+    dt = _cohort_mean(d_ref, p, coef_ref, scale_ref)
     dt_out_ref[...] = dt.astype(dt_out_ref.dtype)
     w = w_ref[...].astype(jnp.float32)
     w_out_ref[...] = (w - eta_ref[0] * dt).astype(w_out_ref.dtype)
 
 
-def batched_epilogue(d3: jnp.ndarray, p2: jnp.ndarray, w2: jnp.ndarray,
-                     coefs, scales, eta_g, *, rows: int = None,
-                     interpret: bool = True):
-    """Fused FedDPC server epilogue over the STACKED cohort in one grid.
-
-    d3: (K, M, 128) client-stacked deltas; p2/w2: (M, 128) delta_prev /
-    params; coefs/scales: (K,) per-client scalars from the reduction pass.
-    Returns (new_w2, delta_t2), both (M, 128): one HBM pass over the
-    (K+2)·M·128 input floats instead of K separate epilogue calls (which
-    re-read prev K times and leave the mean + param update as extra
-    passes). K·rows·128 f32 must fit VMEM, so the row block shrinks as K
-    grows (default 512/K, floor 8). M must be a multiple of the row block
-    (ops.py pads; full blocks avoid partial-block padding semantics).
-    """
+def _cohort_call(kernel, scalars, d3, p2, w2, rows, interpret):
+    """Shared grid of the K-resident epilogues: the (K,) scalars whole in
+    SMEM, one (K, rows, 128) cohort block per row block. Returns
+    (new_w2, delta_t2) trimmed back to the caller's M."""
     k, m, lane = d3.shape
     assert lane == LANE, d3.shape
-    rows = min(rows or max(8, DEFAULT_ROWS // max(1, k)), m)
-    while m % rows:                 # largest divisor <= target (trace-time)
-        rows -= 1
-    grid = (pl.cdiv(m, rows),)
-    coefs = jnp.asarray(coefs, jnp.float32).reshape(k)
-    scales = jnp.asarray(scales, jnp.float32).reshape(k)
-    eta = jnp.asarray(eta_g, jnp.float32).reshape(1)
-    return pl.pallas_call(
-        _batched_epilogue_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((k,), lambda i: (0,)),       # coefs (broadcast)
-            pl.BlockSpec((k,), lambda i: (0,)),       # scales
-            pl.BlockSpec((1,), lambda i: (0,)),       # eta_g
+    rows, mp = _plan(m, rows or cohort_rows(k), d3, p2, w2)
+    w_out, dt = pl.pallas_call(
+        kernel,
+        grid=(mp // rows,),
+        in_specs=[_SMEM] * len(scalars) + [
             pl.BlockSpec((k, rows, LANE), lambda i: (0, i, 0)),
             pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
             pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
@@ -153,14 +196,40 @@ def batched_epilogue(d3: jnp.ndarray, p2: jnp.ndarray, w2: jnp.ndarray,
                    pl.BlockSpec((rows, LANE), lambda i: (i, 0))],
         # delta_t is server STATE: emitted f32 to match the jnp path's
         # f32 accumulation whatever the input dtypes
-        out_shape=[jax.ShapeDtypeStruct((m, lane), w2.dtype),
-                   jax.ShapeDtypeStruct((m, lane), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((mp, lane), w2.dtype),
+                   jax.ShapeDtypeStruct((mp, lane), jnp.float32)],
         interpret=interpret,
-    )(coefs, scales, eta, d3, p2, w2)
+    )(*scalars, _pad_rows(d3, mp), _pad_rows(p2, mp), _pad_rows(w2, mp))
+    return w_out[:m], dt[:m]
+
+
+def _scalars(n: int, *values):
+    return [jnp.asarray(v, jnp.float32).reshape(n) for v in values]
+
+
+def batched_epilogue(d3: jnp.ndarray, p2: jnp.ndarray, w2: jnp.ndarray,
+                     coefs, scales, eta_g, *, rows: int = None,
+                     interpret: bool):
+    """Fused FedDPC server epilogue over the STACKED cohort in one grid.
+
+    d3: (K, M, 128) client-stacked deltas; p2/w2: (M, 128) delta_prev /
+    params; coefs/scales: (K,) per-client scalars from the reduction pass.
+    Returns (new_w2, delta_t2), both (M, 128): one HBM pass over the
+    (K+2)·M·128 input floats instead of K separate epilogue calls (which
+    re-read prev K times and leave the mean + param update as extra
+    passes). K·rows·128 elements must fit VMEM, so the row block shrinks
+    as K grows (``cohort_rows``), aligned to the operands' sublane tile.
+    Any M is accepted: rows past M are zero-padded and trimmed.
+    """
+    k = d3.shape[0]
+    return _cohort_call(_batched_epilogue_kernel,
+                        _scalars(k, coefs, scales) + _scalars(1, eta_g),
+                        d3, p2, w2, rows, interpret)
 
 
 def _buffer_fold_kernel(inv_b, coef_ref, scale_ref, wgt_ref, eta_ref,
-                        d_ref, p_ref, w_ref, w_out_ref, dt_out_ref):
+                        d_ref, p_ref, w_ref, w_out_ref, dt_out_ref,
+                        qs_ref=None, qz_ref=None):
     """Buffered-async server fold on one (rows, 128) tile (DESIGN.md §11):
 
         dt  = (1/B) sum_j wgt_j * scale_j * (d_j - coef_j * prev)
@@ -172,7 +241,8 @@ def _buffer_fold_kernel(inv_b, coef_ref, scale_ref, wgt_ref, eta_ref,
     in VMEM across the whole j loop while each d_j block streams
     through. VMEM footprint is O(rows·128) independent of B — a buffer
     of 64 stale updates folds with the same full-size row block the
-    K-resident batched epilogue would have to shrink 64x for.
+    K-resident batched epilogue would have to shrink 64x for. With
+    qs/qz the streamed block is dequantized on the fly.
     """
     j = pl.program_id(1)
 
@@ -181,11 +251,13 @@ def _buffer_fold_kernel(inv_b, coef_ref, scale_ref, wgt_ref, eta_ref,
         dt_out_ref[...] = jnp.zeros_like(dt_out_ref)
 
     d = d_ref[0].astype(jnp.float32)                      # (r, 128)
+    if qs_ref is not None:
+        d = d * qs_ref[j] + qz_ref[j]
     p = p_ref[...].astype(jnp.float32)                    # (r, 128)
     # staleness-discounted projection coefficient: the discount wgt_j
     # multiplies the adaptive scale, the geometry (coef_j) stays raw
-    dt_out_ref[...] += ((wgt_ref[0] * scale_ref[0])
-                        * (d - coef_ref[0] * p)) * inv_b
+    dt_out_ref[...] += ((wgt_ref[j] * scale_ref[j])
+                        * (d - coef_ref[j] * p)) * inv_b
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
@@ -194,9 +266,34 @@ def _buffer_fold_kernel(inv_b, coef_ref, scale_ref, wgt_ref, eta_ref,
                           ).astype(w_out_ref.dtype)
 
 
+def _fold_call(kernel, scalars, d3, p2, w2, rows, interpret):
+    """Shared (blocks, B) grid of the streaming folds, B innermost so the
+    dt block stays resident; scalars whole in SMEM, read at index j."""
+    b, m, lane = d3.shape
+    assert lane == LANE, d3.shape
+    rows, mp = _plan(m, rows or DEFAULT_ROWS, d3, p2, w2)
+    w_out, dt = pl.pallas_call(
+        functools.partial(kernel, 1.0 / b),
+        grid=(mp // rows, b),
+        in_specs=[_SMEM] * len(scalars) + [
+            pl.BlockSpec((1, rows, LANE), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
+            pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
+        ],
+        # output index_maps ignore j -> blocks stay resident across the
+        # inner delta loop (the scatter-accumulate idiom)
+        out_specs=[pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
+                   pl.BlockSpec((rows, LANE), lambda i, j: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((mp, lane), w2.dtype),
+                   jax.ShapeDtypeStruct((mp, lane), jnp.float32)],
+        interpret=interpret,
+    )(*scalars, _pad_rows(d3, mp), _pad_rows(p2, mp), _pad_rows(w2, mp))
+    return w_out[:m], dt[:m]
+
+
 def buffer_fold(d3: jnp.ndarray, p2: jnp.ndarray, w2: jnp.ndarray,
                 coefs, scales, wgts, eta_g, *, rows: int = None,
-                interpret: bool = True):
+                interpret: bool):
     """Staleness-weighted buffered fold over B stacked deltas.
 
     d3: (B, M, 128) buffered deltas; p2/w2: (M, 128) delta_prev/params;
@@ -208,40 +305,14 @@ def buffer_fold(d3: jnp.ndarray, p2: jnp.ndarray, w2: jnp.ndarray,
     ``batched_epilogue`` with mean replaced by an ordered partial-sum
     accumulation (same values up to f32 summation order).
     """
-    b, m, lane = d3.shape
-    assert lane == LANE, d3.shape
-    rows = min(rows or DEFAULT_ROWS, m)
-    while m % rows:                 # largest divisor <= target (trace-time)
-        rows -= 1
-    grid = (pl.cdiv(m, rows), b)    # j (deltas) innermost: dt block resident
-    coefs = jnp.asarray(coefs, jnp.float32).reshape(b)
-    scales = jnp.asarray(scales, jnp.float32).reshape(b)
-    wgts = jnp.asarray(wgts, jnp.float32).reshape(b)
-    eta = jnp.asarray(eta_g, jnp.float32).reshape(1)
-    return pl.pallas_call(
-        functools.partial(_buffer_fold_kernel, 1.0 / b),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # coef_j
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # scale_j
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # staleness wgt_j
-            pl.BlockSpec((1,), lambda i, j: (0,)),    # eta_g (broadcast)
-            pl.BlockSpec((1, rows, LANE), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
-            pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
-        ],
-        # output index_maps ignore j -> blocks stay resident across the
-        # inner delta loop (the scatter-accumulate idiom)
-        out_specs=[pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
-                   pl.BlockSpec((rows, LANE), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((m, lane), w2.dtype),
-                   jax.ShapeDtypeStruct((m, lane), jnp.float32)],
-        interpret=interpret,
-    )(coefs, scales, wgts, eta, d3, p2, w2)
+    b = d3.shape[0]
+    return _fold_call(_buffer_fold_kernel,
+                      _scalars(b, coefs, scales, wgts) + _scalars(1, eta_g),
+                      d3, p2, w2, rows, interpret)
 
 
-def _dequant_batched_epilogue_kernel(coef_ref, scale_ref, qs_ref, qz_ref,
-                                     eta_ref, q_ref, p_ref, w_ref,
+def _dequant_batched_epilogue_kernel(coef_ref, scale_ref, eta_ref, qs_ref,
+                                     qz_ref, q_ref, p_ref, w_ref,
                                      w_out_ref, dt_out_ref):
     """``_batched_epilogue_kernel`` with the codec dequant fused in front
     (DESIGN.md §13): the resident (K, rows, 128) block holds the
@@ -253,14 +324,8 @@ def _dequant_batched_epilogue_kernel(coef_ref, scale_ref, qs_ref, qz_ref,
         dt  = mean_j scale_j * ((q_j * qs_j + qz_j) - coef_j * prev)
         w'  = w - eta_g * dt
     """
-    q = q_ref[...].astype(jnp.float32)                    # (K, r, 128)
-    qs = qs_ref[...].astype(jnp.float32)[:, None, None]
-    qz = qz_ref[...].astype(jnp.float32)[:, None, None]
-    d = q * qs + qz
     p = p_ref[...].astype(jnp.float32)                    # (r, 128)
-    coef = coef_ref[...].astype(jnp.float32)[:, None, None]
-    scale = scale_ref[...].astype(jnp.float32)[:, None, None]
-    dt = jnp.mean(scale * (d - coef * p[None]), axis=0)
+    dt = _cohort_mean(q_ref, p, coef_ref, scale_ref, qs_ref, qz_ref)
     dt_out_ref[...] = dt.astype(dt_out_ref.dtype)
     w = w_ref[...].astype(jnp.float32)
     w_out_ref[...] = (w - eta_ref[0] * dt).astype(w_out_ref.dtype)
@@ -269,54 +334,26 @@ def _dequant_batched_epilogue_kernel(coef_ref, scale_ref, qs_ref, qz_ref,
 def dequant_batched_epilogue(q3: jnp.ndarray, p2: jnp.ndarray,
                              w2: jnp.ndarray, coefs, scales, eta_g,
                              qscales, qzeros, *, rows: int = None,
-                             interpret: bool = True):
+                             interpret: bool):
     """``batched_epilogue`` over a QUANTIZED cohort stack.
 
     q3: (K, M, 128) int8/bf16 quantized deltas; qscales/qzeros: (K,)
     per-client dequant scalars for this leaf (repro/codec wire format:
     ``dequant(q) = q * qscale + qzero``); everything else as
-    ``batched_epilogue``. The grid/blocking is identical — only the
-    resident cohort block shrinks by the quantized dtype's itemsize.
-    NOTE: int8's real-TPU min sublane tile is (32, 128), so the rows
-    floor of 8 is an interpret-mode/bench layout; real-TPU autotuning
-    stays with the carried-over ROADMAP item. Zero-padded rows dequant
-    to qzero (not 0) — callers must trim outputs to the true length
-    (ops.py does), exactly as they already trim the f32 path.
+    ``batched_epilogue``. The grid is the same; the row block aligns to
+    the quantized dtype's taller sublane tile (32 rows for int8, 16 for
+    bf16). Zero-padded rows dequant to qzero (not 0) and are trimmed,
+    exactly as the f32 path trims its zero padding.
     """
-    k, m, lane = q3.shape
-    assert lane == LANE, q3.shape
-    rows = min(rows or max(8, DEFAULT_ROWS // max(1, k)), m)
-    while m % rows:                 # largest divisor <= target (trace-time)
-        rows -= 1
-    grid = (pl.cdiv(m, rows),)
-    coefs = jnp.asarray(coefs, jnp.float32).reshape(k)
-    scales = jnp.asarray(scales, jnp.float32).reshape(k)
-    qscales = jnp.asarray(qscales, jnp.float32).reshape(k)
-    qzeros = jnp.asarray(qzeros, jnp.float32).reshape(k)
-    eta = jnp.asarray(eta_g, jnp.float32).reshape(1)
-    return pl.pallas_call(
-        _dequant_batched_epilogue_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((k,), lambda i: (0,)),       # coefs (broadcast)
-            pl.BlockSpec((k,), lambda i: (0,)),       # scales
-            pl.BlockSpec((k,), lambda i: (0,)),       # dequant scales
-            pl.BlockSpec((k,), lambda i: (0,)),       # dequant zero-points
-            pl.BlockSpec((1,), lambda i: (0,)),       # eta_g
-            pl.BlockSpec((k, rows, LANE), lambda i: (0, i, 0)),
-            pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-        ],
-        out_specs=[pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-                   pl.BlockSpec((rows, LANE), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((m, lane), w2.dtype),
-                   jax.ShapeDtypeStruct((m, lane), jnp.float32)],
-        interpret=interpret,
-    )(coefs, scales, qscales, qzeros, eta, q3, p2, w2)
+    k = q3.shape[0]
+    return _cohort_call(_dequant_batched_epilogue_kernel,
+                        _scalars(k, coefs, scales) + _scalars(1, eta_g)
+                        + _scalars(k, qscales, qzeros),
+                        q3, p2, w2, rows, interpret)
 
 
 def _dequant_buffer_fold_kernel(inv_b, coef_ref, scale_ref, wgt_ref,
-                                qs_ref, qz_ref, eta_ref, q_ref, p_ref,
+                                eta_ref, qs_ref, qz_ref, q_ref, p_ref,
                                 w_ref, w_out_ref, dt_out_ref):
     """``_buffer_fold_kernel`` with the per-arrival dequant fused in: the
     staleness discount wgt_j and the dequant scale qs_j COMPOSE as plain
@@ -324,63 +361,23 @@ def _dequant_buffer_fold_kernel(inv_b, coef_ref, scale_ref, wgt_ref,
 
         dt = (1/B) sum_j wgt_j * scale_j * ((q_j * qs_j + qz_j) - coef_j * prev)
     """
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        dt_out_ref[...] = jnp.zeros_like(dt_out_ref)
-
-    d = q_ref[0].astype(jnp.float32) * qs_ref[0] + qz_ref[0]   # (r, 128)
-    p = p_ref[...].astype(jnp.float32)                          # (r, 128)
-    dt_out_ref[...] += ((wgt_ref[0] * scale_ref[0])
-                        * (d - coef_ref[0] * p)) * inv_b
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        w = w_ref[...].astype(jnp.float32)
-        w_out_ref[...] = (w - eta_ref[0] * dt_out_ref[...]
-                          ).astype(w_out_ref.dtype)
+    _buffer_fold_kernel(inv_b, coef_ref, scale_ref, wgt_ref, eta_ref,
+                        q_ref, p_ref, w_ref, w_out_ref, dt_out_ref,
+                        qs_ref, qz_ref)
 
 
 def dequant_buffer_fold(q3: jnp.ndarray, p2: jnp.ndarray, w2: jnp.ndarray,
                         coefs, scales, wgts, eta_g, qscales, qzeros, *,
-                        rows: int = None, interpret: bool = True):
+                        rows: int = None, interpret: bool):
     """``buffer_fold`` over a QUANTIZED arrival buffer: q3 (B, M, 128)
     int8/bf16, qscales/qzeros (B,) per-arrival dequant scalars; the
     scatter-accumulate grid is unchanged (B innermost, dt resident) and
     each streamed block is dequantized on the fly."""
-    b, m, lane = q3.shape
-    assert lane == LANE, q3.shape
-    rows = min(rows or DEFAULT_ROWS, m)
-    while m % rows:                 # largest divisor <= target (trace-time)
-        rows -= 1
-    grid = (pl.cdiv(m, rows), b)    # j (arrivals) innermost: dt resident
-    coefs = jnp.asarray(coefs, jnp.float32).reshape(b)
-    scales = jnp.asarray(scales, jnp.float32).reshape(b)
-    wgts = jnp.asarray(wgts, jnp.float32).reshape(b)
-    qscales = jnp.asarray(qscales, jnp.float32).reshape(b)
-    qzeros = jnp.asarray(qzeros, jnp.float32).reshape(b)
-    eta = jnp.asarray(eta_g, jnp.float32).reshape(1)
-    return pl.pallas_call(
-        functools.partial(_dequant_buffer_fold_kernel, 1.0 / b),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # coef_j
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # scale_j
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # staleness wgt_j
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # dequant scale_j
-            pl.BlockSpec((1,), lambda i, j: (j,)),    # dequant zero_j
-            pl.BlockSpec((1,), lambda i, j: (0,)),    # eta_g (broadcast)
-            pl.BlockSpec((1, rows, LANE), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
-            pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
-        ],
-        out_specs=[pl.BlockSpec((rows, LANE), lambda i, j: (i, 0)),
-                   pl.BlockSpec((rows, LANE), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((m, lane), w2.dtype),
-                   jax.ShapeDtypeStruct((m, lane), jnp.float32)],
-        interpret=interpret,
-    )(coefs, scales, wgts, qscales, qzeros, eta, q3, p2, w2)
+    b = q3.shape[0]
+    return _fold_call(_dequant_buffer_fold_kernel,
+                      _scalars(b, coefs, scales, wgts) + _scalars(1, eta_g)
+                      + _scalars(b, qscales, qzeros),
+                      q3, p2, w2, rows, interpret)
 
 
 def _epilogue_kernel(coef_ref, scale_ref, d_ref, p_ref, out_ref):
@@ -393,23 +390,18 @@ def _epilogue_kernel(coef_ref, scale_ref, d_ref, p_ref, out_ref):
 
 def fused_epilogue(d2: jnp.ndarray, p2: jnp.ndarray, coef, scale, *,
                    rows: int = DEFAULT_ROWS,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool) -> jnp.ndarray:
     """out = scale * (d2 - coef * p2), one HBM pass. d2/p2: (M, 128)."""
     m = d2.shape[0]
-    rows = min(rows, m)
-    grid = (pl.cdiv(m, rows),)
-    coef = jnp.asarray(coef, jnp.float32).reshape(1)
-    scale = jnp.asarray(scale, jnp.float32).reshape(1)
-    return pl.pallas_call(
+    rows, mp = _plan(m, rows, d2, p2)
+    out = pl.pallas_call(
         _epilogue_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),  # coef (broadcast to blocks)
-            pl.BlockSpec((1,), lambda i: (0,)),  # scale
-            pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-            pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-        ],
+        grid=(mp // rows,),
+        in_specs=[_SMEM, _SMEM,
+                  pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
+                  pl.BlockSpec((rows, LANE), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(d2.shape, d2.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp, LANE), d2.dtype),
         interpret=interpret,
-    )(coef, scale, d2, p2)
+    )(*_scalars(1, coef, scale), _pad_rows(d2, mp), _pad_rows(p2, mp))
+    return out[:m]

@@ -81,7 +81,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref,
 
 def flash_attention_gqa(q, k, v, q_pos, k_pos, *, window: int = 0,
                         soft_cap: float = 0.0, block_q: int = 128,
-                        block_k: int = 256, interpret: bool = True):
+                        block_k: int = 256, interpret: bool):
     """q: (B, Sq, H, D); k/v: (B, Sk, KV, D); q_pos: (B, Sq); k_pos: (B, Sk).
     Returns (B, Sq, H, D). Sq % block_q == 0 and Sk % block_k == 0 required
     (ops.py pads)."""

@@ -8,6 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention import kernel as K
 
 
@@ -16,7 +17,7 @@ from repro.kernels.flash_attention import kernel as K
                                     "block_k", "interpret"))
 def flash_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                     soft_cap: float = 0.0, block_q: int = 128,
-                    block_k: int = 256, interpret: bool = True):
+                    block_k: int = 256, interpret: bool = None):
     """Same contract as models.attention.sdpa: q (B,Sq,H,D), k/v (B,Sk,KV,D),
     positions (B,Sq)/(B,Sk) int32 (-1 = empty cache slot)."""
     b, sq, h, d = q.shape
@@ -36,5 +37,5 @@ def flash_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
         k_pos = jnp.pad(k_pos, ((0, 0), (0, pk)), constant_values=-1)
     out = K.flash_attention_gqa(q, k, v, q_pos, k_pos, window=window,
                                 soft_cap=soft_cap, block_q=bq, block_k=bk,
-                                interpret=interpret)
+                                interpret=resolve_interpret(interpret))
     return out[:, :sq]

@@ -63,7 +63,7 @@ def _ssm_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, dskip_ref,
 
 
 def ssm_scan(u, dt, b, c, a, d_skip, *, ts: int = TS, blk_d: int = BLK_D,
-             interpret: bool = True):
+             interpret: bool):
     """u/dt: (B, S, D_in) — u post-conv/silu, dt post-softplus (f32).
     b/c: (B, S, N) f32. a: (D_in, N) f32 (A = a, already negative).
     d_skip: (D_in,) f32. Returns (y (B, S, D_in), h_final (B, D_in, N) f32).

@@ -7,11 +7,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.ssm_scan import kernel as K
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssm_scan(u, dt, b, c, a, d_skip, interpret: bool = True):
+def ssm_scan(u, dt, b, c, a, d_skip, interpret: bool = None):
     bsz, s, d_in = u.shape
     ts = min(K.TS, max(8, s))
     blk = min(K.BLK_D, d_in)
@@ -26,5 +27,5 @@ def ssm_scan(u, dt, b, c, a, d_skip, interpret: bool = True):
         a = jnp.pad(a, ((0, pd), (0, 0)))
         d_skip = jnp.pad(d_skip, (0, pd))
     y, h = K.ssm_scan(u, dt, b, c, a, d_skip, ts=ts, blk_d=blk,
-                      interpret=interpret)
+                      interpret=resolve_interpret(interpret))
     return y[:, :s, :d_in], h[:, :d_in, :]

@@ -169,28 +169,39 @@ def spawn_local(argv: Sequence[str], num_processes: int, *,
                 timeout_s: float = 600.0,
                 capture: bool = True):
     """Run ``argv`` as ``num_processes`` local processes forming one
-    distributed jax job (the offline-CI stand-in for a real multi-host
-    launch — coordinator on 127.0.0.1, no external network).
+    distributed jax job on the CPU: the stand-in for a real multi-host
+    launch (coordinator on 127.0.0.1, no external network).
 
     Each child gets the REPRO_DIST_* contract plus ``JAX_PLATFORMS=cpu``
     and, when ``devices_per_process`` is set, an XLA_FLAGS host-device
     override so every "host" exposes that many CPU devices. The children
     must call :func:`maybe_initialize` before their first device query.
+    An accelerator belongs to one process at a time and this launcher
+    binds none to its children, so a caller environment (``os.environ``
+    updated by ``env``) that asks for any platform other than the CPU
+    is refused with ValueError instead of being overridden or handing
+    every child the same chips.
 
     Returns the list of ``subprocess.CompletedProcess``-like results
     (returncode/stdout/stderr per child); raises RuntimeError if any
     child fails, with the failing children's tails in the message.
     """
+    base_env = {**os.environ, **(env or {})}
+    asked = base_env.get("JAX_PLATFORMS", "")
+    if asked not in ("", "cpu"):
+        raise ValueError(
+            f"spawn_local runs its {num_processes} children on the CPU, "
+            f"but JAX_PLATFORMS={asked!r} asks for another platform: an "
+            "accelerator takes one process per chip, and this launcher "
+            "binds no chip to any child")
     coord = f"127.0.0.1:{free_port()}"
     procs = []
     for pid in range(num_processes):
-        child_env = dict(os.environ)
-        if env:
-            child_env.update(env)
+        child_env = dict(base_env)
         child_env[ENV_COORD] = coord
         child_env[ENV_NPROCS] = str(num_processes)
         child_env[ENV_PID] = str(pid)
-        child_env.setdefault("JAX_PLATFORMS", "cpu")
+        child_env["JAX_PLATFORMS"] = "cpu"
         if devices_per_process is not None:
             child_env[ENV_LOCAL_DEVICES] = str(devices_per_process)
             flags = child_env.get("XLA_FLAGS", "")

@@ -14,6 +14,7 @@ multi-pod = 2 pods over DCN. Axis meaning (DESIGN.md §2):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # v5e hardware constants for the roofline (per chip)
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -23,17 +24,11 @@ DCN_BW = 6.25e9                   # B/s per host across pods (50 Gb/s)
 HBM_BYTES = 16e9                  # v5e HBM capacity
 
 
-def _mesh_kwargs(n):
-    # AxisType landed after jax 0.4.x; Auto is the default there anyway,
-    # so on older jax we simply omit the kwarg.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return {} if axis_type is None else {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_cohort_mesh(num_devices: int = None, axis: str = "clients",
@@ -66,19 +61,19 @@ def make_cohort_mesh(num_devices: int = None, axis: str = "clients",
     XLA_FLAGS=--xla_force_host_platform_device_count=8."""
     n = num_devices or len(jax.devices())
     if model <= 1:
-        return jax.make_mesh((n,), (axis,), **_mesh_kwargs(1))
+        return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
     if n % model:
         raise ValueError(
             f"model={model} does not divide the {n} available devices; "
             f"a ({axis}, model) mesh needs clients x model == devices")
     return jax.make_mesh((n // model, model), (axis, "model"),
-                         **_mesh_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many local devices exist (tests)."""
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_mesh_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_info(mesh) -> dict:

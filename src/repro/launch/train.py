@@ -50,6 +50,7 @@ from repro.data.synthetic import make_lm_dataset
 from repro.ingest import (CIFAR10Source, CIFAR100Source, ListDataSource,
                           StreamingImageSource, TinyImageNetSource,
                           build_federated_image_data)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.models.vision import (VisionConfig, init_vision, vision_accuracy,
                                  vision_loss_fn)
@@ -91,7 +92,10 @@ def build_vision_task(args):
     params = init_vision(vc, jax.random.PRNGKey(args.seed))
     loss_fn = functools.partial(vision_loss_fn, vc)
     te_x, te_y = jnp.asarray(te_x), jnp.asarray(te_y)
-    eval_fn = jax.jit(lambda p: vision_accuracy(vc, p, te_x, te_y))
+    # the test set is an argument, not a closed-over constant: baked in,
+    # it would make the eval program as large as the test set
+    accuracy = jax.jit(functools.partial(vision_accuracy, vc))
+    eval_fn = lambda p: accuracy(p, te_x, te_y)
     return params, loss_fn, source, eval_fn, args.clients
 
 
@@ -144,7 +148,7 @@ def build_sampler(args, source, num_clients: int, cohort: int):
     raise ValueError(args.sampler)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="lenet5",
                     choices=["lenet5", "resnet18-gn", *ARCH_IDS])
@@ -291,20 +295,12 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true",
                     help="restore the latest TrainerState from --ckpt-dir "
                          "and continue the run exactly where it stopped")
-    args = ap.parse_args(argv)
+    return ap
 
-    # multi-process jobs (DESIGN.md §15): wire jax.distributed from the
-    # REPRO_DIST_* environment BEFORE the first device query; a no-op in
-    # single-process runs
-    from repro.launch.distributed import maybe_initialize
-    maybe_initialize()
 
-    if args.model in ("lenet5", "resnet18-gn"):
-        params, loss_fn, source, eval_fn, k = build_vision_task(args)
-    else:
-        params, loss_fn, source, eval_fn, k = build_lm_task(args)
-
-    cohort = max(1, int(round(k * args.participation)))
+def build_configs(args, num_clients: int):
+    """(AlgoConfig, ExecConfig) the CLI runs for parsed ``args``."""
+    cohort = max(1, int(round(num_clients * args.participation)))
     algo = AlgoConfig(name=args.algorithm, eta_l=args.eta_l,
                       eta_g=args.eta_g,
                       hyper=default_hyper(args.algorithm, lam=args.lam),
@@ -326,7 +322,26 @@ def main(argv=None):
         health_log=args.health_log, edges=args.edges,
         decode_workers=args.decode_workers,
         batch_size=args.batch_size, local_epochs=args.local_epochs)
-    sampler = build_sampler(args, source, k, cohort)
+    return algo, cfg
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+
+    # multi-process jobs (DESIGN.md §15): wire jax.distributed from the
+    # REPRO_DIST_* environment BEFORE the first device query; a no-op in
+    # single-process runs
+    from repro.launch.distributed import maybe_initialize
+    maybe_initialize()
+    enable_compile_cache()
+
+    if args.model in ("lenet5", "resnet18-gn"):
+        params, loss_fn, source, eval_fn, k = build_vision_task(args)
+    else:
+        params, loss_fn, source, eval_fn, k = build_lm_task(args)
+
+    algo, cfg = build_configs(args, k)
+    sampler = build_sampler(args, source, k, cfg.clients_per_round)
     runtime = None
     if args.async_buffer or args.round_deadline is not None:
         from repro.core.runtime import make_runtime

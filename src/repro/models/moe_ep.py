@@ -34,7 +34,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.layers import linear
@@ -176,13 +175,13 @@ def moe_forward_ep(cfg, p, x, *, mesh: Mesh, expert_axis=None,
 
     xt = x.reshape(t_total, d)
     spec_tok = P(expert_axis, None)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_block, mesh=mesh,
         in_specs=(spec_tok, P(), P(expert_axis, None, model_axis),
                   P(expert_axis, None, model_axis),
                   P(expert_axis, model_axis, None)),
         out_specs=(spec_tok, P()),
-        check_rep=False,
+        check_vma=False,
     )(xt, p["router"]["w"], p["gate"], p["up"], p["down"])
     out = out.reshape(b, s, d)
 

@@ -21,7 +21,7 @@ of the acceptance criteria; the model dims of the toy task (16, 4) are
 Invoked by tests/test_regime_matrix.py with either
     --cells algo:sampler:regime:{P|N}[,...]     matrix cells to check
     --cross-mesh-resume                         save on 2-axis, resume 1-D
-    --kernel-fallback                           use_kernel under sharded2d
+    --kernel-fallback                           use_kernel under sharded1d/2d
 """
 import argparse
 import os
@@ -201,14 +201,16 @@ def check_codec_identity_bitwise():
 
 
 def check_kernel_fallback():
-    """FedDPCHyper(use_kernel=True) under the two-axis mesh must fall
-    back to the reference epilogue (model-sharded leaves) and still
-    match the serial reference."""
-    tr = run_cell("feddpc", "uniform", "sharded2d", True, use_kernel=True)
+    """FedDPCHyper(use_kernel=True) on a partitioned round — the 1-D
+    client mesh and the two-axis mesh — must fall back to the reference
+    epilogue (a Mosaic kernel cannot be partitioned) and still match the
+    serial reference."""
     ref = reference("feddpc", "uniform")
-    assert_trees_close(tr.params, ref.params)
-    assert_trees_close(tr.server_state, ref.server_state)
-    print("[matrix] use_kernel fallback under sharded2d OK")
+    for regime in ("sharded1d", "sharded2d"):
+        tr = run_cell("feddpc", "uniform", regime, True, use_kernel=True)
+        assert_trees_close(tr.params, ref.params)
+        assert_trees_close(tr.server_state, ref.server_state)
+        print(f"[matrix] use_kernel fallback under {regime} OK")
 
 
 def main():

@@ -56,3 +56,30 @@ def test_spawn_local_surfaces_a_failing_child():
     with pytest.raises(RuntimeError, match="child 1 exited 3"):
         spawn_local([sys.executable, WORKER, "--fail"], 2,
                     devices_per_process=1, env=ENV, timeout_s=300)
+
+
+@pytest.mark.parametrize("asked", [None, "", "cpu"])
+def test_spawn_local_puts_every_child_on_the_cpu(asked, monkeypatch):
+    """Whatever CPU-compatible JAX_PLATFORMS the caller has (unset,
+    empty, cpu), every child runs with JAX_PLATFORMS=cpu."""
+    if asked is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", asked)
+    probe = "import os; print(os.environ['JAX_PLATFORMS'])"
+    results = spawn_local([sys.executable, "-c", probe], 2, timeout_s=60)
+    assert [out.strip() for _rc, out, _err in results] == ["cpu", "cpu"]
+
+
+@pytest.mark.parametrize("where", ["os.environ", "env"])
+def test_spawn_local_refuses_an_accelerator_platform(where, monkeypatch):
+    """A caller asking for the TPU is refused before any child starts: an
+    accelerator takes one process per chip and spawn_local binds none."""
+    env = None
+    if where == "env":
+        env = {"JAX_PLATFORMS": "tpu"}
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(ValueError, match="one process per chip"):
+        spawn_local([sys.executable, "-c", "raise SystemExit(7)"], 2,
+                    env=env, timeout_s=60)

@@ -79,7 +79,7 @@ def test_batched_server_epilogue_tree_fuzz(k, n, zero_prev):
 
 @given(st.integers(1, 6), st.sampled_from([False, True]))
 def test_server_step_model_sharded_falls_back_to_reference(k, round1):
-    """feddpc.server_step(use_kernel=True, model_sharded=True) must take
+    """feddpc.server_step(use_kernel=True, partitioned=True) must take
     the reference epilogue (the Pallas path would flatten partitioned
     leaves); on one device that makes it BITWISE equal to the jnp path,
     and the reduction-pass scalars are untouched by the flag."""
@@ -94,7 +94,7 @@ def test_server_step_model_sharded_falls_back_to_reference(k, round1):
                                  0.1, 1.0, use_kernel=False)
     got_out = feddpc.server_step({"delta_prev": prev}, params, deltas,
                                  0.1, 1.0, use_kernel=True,
-                                 model_sharded=True)
+                                 partitioned=True)
     for a, b in zip(jax.tree.leaves(ref_out), jax.tree.leaves(got_out)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

@@ -40,7 +40,8 @@ def test_feddpc_batched_epilogue_sweep(k, n, rng):
     w2 = jax.random.normal(ks[2], (m, 128))
     coefs = jax.random.normal(ks[3], (k,))
     scales = 1.0 + jnp.abs(jax.random.normal(ks[4], (k,)))
-    got_w, got_dt = fp_kernel.batched_epilogue(d3, p2, w2, coefs, scales, 0.3)
+    got_w, got_dt = fp_kernel.batched_epilogue(d3, p2, w2, coefs, scales, 0.3,
+                                               interpret=True)
     want_w, want_dt = fp_ref.batched_epilogue_ref(d3, p2, w2, coefs, scales,
                                                   0.3)
     np.testing.assert_allclose(got_w, want_w, rtol=2e-5, atol=2e-5)
@@ -92,7 +93,7 @@ def test_feddpc_buffer_fold_sweep(b, n, rng):
     scales = 1.0 + jnp.abs(jax.random.normal(ks[4], (b,)))
     wgts = jax.random.uniform(ks[5], (b,), minval=0.1, maxval=1.0)
     got_w, got_dt = fp_kernel.buffer_fold(d3, p2, w2, coefs, scales, wgts,
-                                          0.3)
+                                          0.3, interpret=True)
     want_w, want_dt = fp_ref.buffer_fold_ref(d3, p2, w2, coefs, scales,
                                              wgts, 0.3)
     np.testing.assert_allclose(got_w, want_w, rtol=2e-5, atol=2e-5)
@@ -114,10 +115,11 @@ def test_feddpc_buffer_fold_unit_weights_match_batched_epilogue(rng):
     scales = 1.0 + jnp.abs(jax.random.normal(ks[4], (b,)))
     ones = jnp.ones((b,))
     got_w, got_dt = fp_kernel.buffer_fold(d3, p2, w2, coefs, scales, ones,
-                                          0.3)
+                                          0.3, interpret=True)
     rows = max(8, fp_kernel.DEFAULT_ROWS // b)
     want_w, want_dt = fp_kernel.batched_epilogue(d3, p2, w2, coefs, scales,
-                                                 0.3, rows=rows)
+                                                 0.3, rows=rows,
+                                                 interpret=True)
     np.testing.assert_allclose(got_w, want_w, rtol=2e-6, atol=2e-6)
     np.testing.assert_allclose(got_dt, want_dt, rtol=2e-6, atol=2e-6)
 

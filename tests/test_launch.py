@@ -32,14 +32,43 @@ def test_serve_encdec_smoke():
 
 
 @pytest.mark.slow
-def test_train_cli_smoke(tmp_path):
+def test_train_cli_smoke(tmp_path, monkeypatch):
+    from repro.launch.compile_cache import ENV_VAR
     from repro.launch.train import main
+    # a cache placed from outside: main() then leaves JAX's (off) cache
+    # setting alone, instead of caching this worker's later compiles
+    # into the checkout
+    monkeypatch.setenv(ENV_VAR, str(tmp_path / "jax_cache"))
     rc = main(["--model", "lenet5", "--rounds", "2", "--clients", "6",
                "--participation", "0.5", "--samples-per-class", "20",
                "--batch-size", "16", "--eval-every", "1",
                "--out", str(tmp_path / "hist.json")])
     assert rc == 0
     assert (tmp_path / "hist.json").exists()
+
+
+@pytest.mark.parametrize("placed", [None, "/elsewhere/cache"])
+def test_compile_cache_directory(placed, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to the checkout's fixed .jax_cache directory."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    if placed is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, placed)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if placed is None:
+        repo = Path(__file__).resolve().parents[1]
+        assert got == now == str(repo / ".jax_cache")
+    else:
+        assert got == placed and now == before
 
 
 def test_microbatched_train_step_equivalence(rng):

@@ -56,8 +56,8 @@ SUBPROC = textwrap.dedent("""
     # aux estimator normalizes per token-shard; groups=2 is the matching
     # gshard grouping for a 2-way expert axis
     _, a1g = moe_mod.moe_forward(cfg, p, x, groups=2)
-    from repro.launch.mesh import _mesh_kwargs
-    mesh = jax.make_mesh((2, 2), ("data", "model"), **_mesh_kwargs(2))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with mesh:
         o2, a2 = jax.jit(lambda pp, xx: moe_ep.moe_forward_ep(
             cfg, pp, xx, mesh=mesh))(p, x)
