@@ -240,7 +240,8 @@ def bench(overrides: dict, *, params, loss_fn, batch_fn, k: int,
             tr.run_round(t)
         recs = [tr.run_round(t) for t in range(warmup, warmup + rounds)]
     times = np.asarray([r.seconds for r in recs])
-    ingest = np.asarray([r.ingest_seconds for r in recs])
+    ingest = np.asarray([r.ingest_host_seconds + r.ingest_device_seconds
+                         for r in recs])
     return {"mean_s": float(times.mean()), "p50_s": float(np.median(times)),
             "p90_s": float(np.percentile(times, 90)),
             "min_s": float(times.min()),
@@ -622,7 +623,8 @@ def run_multihost(clients: int = 16, rounds: int = 10, warmup: int = 2,
                 "p50_s": float(np.median(times)),
                 "min_s": float(times.min()),
                 "ingest_mean_s": float(np.mean(
-                    [r.ingest_seconds for r in recs])),
+                    [r.ingest_host_seconds + r.ingest_device_seconds
+                     for r in recs])),
                 "rounds": int(rounds),
                 "server_fanin": int(edges if "edges" in overrides
                                     else clients),

@@ -72,7 +72,6 @@ from __future__ import annotations
 import itertools
 import os
 import threading
-import time
 import warnings
 from dataclasses import asdict, dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -87,6 +86,7 @@ from repro.core.baselines import ServerAlgo, default_hyper, make_algorithm
 from repro.core.samplers import ClientSampler, UniformSampler
 from repro.ingest import (CohortIngestPipeline, CohortPlacer, DataSource,
                           as_data_source, stack_batches)
+from repro.trace import span, timed
 
 PyTree = Any
 
@@ -355,17 +355,34 @@ class RoundRecord:
     round: int
     train_loss: float
     test_accuracy: Optional[float] = None
+    # the seconds are the durations of the round's host spans
+    # (repro.trace, DESIGN.md §16): the whole run_round (fl.round),
+    # eval included
     seconds: float = 0.0
-    # total time this round spent blocked on cohort ingest —
-    # ingest_host_seconds + ingest_device_seconds (kept for continuity
-    # with pre-split records/benches)
-    ingest_seconds: float = 0.0
     # blocked on HOST staging: sampling + source reads + decode +
-    # stacking (with prefetch on, just the wait for the staged round)
+    # stacking (with prefetch on, just the wait for the staged round:
+    # fl.ingest.wait)
     ingest_host_seconds: float = 0.0
     # blocked on DEVICE placement at dispatch (H2D transfer); ~0 when
     # ExecConfig.device_stage moved it onto the staging thread
     ingest_device_seconds: float = 0.0
+    # fl.ingest.stage: staging this round (sample, read, stack, and
+    # placement where the staging thread places), on whichever thread
+    ingest_stage_seconds: float = 0.0
+    # fl.ingest.place: the H2D placement, on whichever thread
+    ingest_place_seconds: float = 0.0
+    # fl.round.sync: reading the round's outputs back, i.e. waiting for
+    # the device to finish the round program (fused rounds only)
+    sync_seconds: float = 0.0
+    # staged rounds waiting in the ring when this round asked for its
+    # own: 0 means the round waited on the staging thread (always 0
+    # without prefetch)
+    ingest_ready: int = 0
+    # the staged (clients x steps) mask: its unmasked slots and its
+    # size, i.e. local steps that train on data and local steps the
+    # round computes (this process's rows; 0 in the async regime)
+    steps_useful: int = 0
+    steps_computed: int = 0
     diagnostics: Dict[str, float] = field(default_factory=dict)
     # buffered-async regime only (DESIGN.md §11): staleness statistics
     # of the arrivals this server step folded — kept OUT of diagnostics
@@ -832,6 +849,9 @@ class FederatedTrainer:
                                                     exec_cfg)
         self._start_round = 0                    # advanced by restore()
         self._pending_eval = None                # (RoundRecord, Future)
+        # the round's guard observation, handed to the guard while the
+        # round's record is made: (norms, quarantined, clipped)
+        self._guard_obs = None
         self._async_eval = eval_fn is not None and exec_cfg.async_eval
         # sampling-time snapshots for save(): the prefetcher draws the RNG
         # ahead of consumed rounds, so each round's pre-draw state is
@@ -1162,7 +1182,8 @@ class FederatedTrainer:
 
     def _round_batches(self, clients: Sequence[int], t: int):
         lists = self._pipeline.client_lists(clients, t)
-        return [stack_batches(b, self._max_batches) for b in lists]
+        with span("fl.ingest.stack", round=t):
+            return [stack_batches(b, self._max_batches) for b in lists]
 
     def _run_round_vectorized(self, t: int):
         staged = (self._pipeline.get(t) if self.cfg.prefetch
@@ -1171,27 +1192,32 @@ class FederatedTrainer:
                  or self._guard is not None or self._codec_stochastic
                  or self._codec_ef or self._server_opt is not None)
         try:
+            extra: Dict[str, Any] = staged.record_fields()
+            n = len(staged.clients)
             if not chaos:
-                self.params, self.server_state, losses, diag = \
-                    self._cohort_round(
-                        self.server_state, self.params, staged.batches,
-                        staged.masks, staged.ids)
+                with span("fl.round.dispatch", round=t):
+                    self.params, self.server_state, losses, diag = \
+                        self._cohort_round(
+                            self.server_state, self.params, staged.batches,
+                            staged.masks, staged.ids)
                 # syncs on the round's result: after this the device is
                 # done with the inputs and the staging slot is reusable;
                 # dummy padded clients sit past the real K and report
                 # loss 0. Multi-process: losses left the program
                 # replicated, so the host read works on every process.
-                n = len(staged.clients)
-                train_loss = float(np.asarray(losses)[:n].mean())
-                return (train_loss, diag, staged.host_seconds,
-                        staged.device_seconds, self._comm_fields(n))
+                # One read of losses and diagnostics together: a read
+                # per diagnostic costs a transfer each.
+                with timed("fl.round.sync", round=t) as sync:
+                    losses_h, diag = jax.device_get((losses, diag))
+                train_loss = float(losses_h[:n].mean())
+                extra["sync_seconds"] = sync.seconds
+                extra.update(self._comm_fields(n))
+                return train_loss, diag, extra
             # ---- chaos-hardened / codec-extra round (DESIGN.md §12,
             # §13): same program, extended by the fixed-order extras ----
-            n = len(staged.clients)
             kp = int(np.shape(staged.ids)[0])        # padded cohort size
             args = [self.server_state, self.params, staged.batches,
                     staged.masks, staged.ids]
-            extra: Dict[str, Any] = {}
             live = np.ones(n, bool)
             shipped = np.ones(n, bool)
             if self._inject_deltas:
@@ -1233,25 +1259,28 @@ class FederatedTrainer:
                 args.append(self._ef)
             if self._server_opt is not None:
                 args.append(self._opt_state)
-            outs = list(self._cohort_round(*args))
+            with span("fl.round.dispatch", round=t):
+                outs = list(self._cohort_round(*args))
             if self._server_opt is not None:
                 self._opt_state = outs.pop()
             if self._codec_ef:
                 self._ef = outs.pop()
-            if self._guard is not None:
-                gstats = outs.pop()
+            gstats = outs.pop() if self._guard is not None else None
             self.params, self.server_state, losses, diag = outs
+            with timed("fl.round.sync", round=t) as sync:
+                losses_h, diag, gstats = jax.device_get((losses, diag,
+                                                         gstats))
+            losses_h = losses_h[:n]
+            extra["sync_seconds"] = sync.seconds
             if self._guard is not None:
-                q = np.asarray(gstats["quarantined"])[:n]
-                c = np.asarray(gstats["clipped"])[:n]
-                norms = np.asarray(gstats["norm"])[:n]
+                q, c, norms = (np.asarray(gstats[k])[:n] for k in
+                               ("quarantined", "clipped", "norm"))
                 # deadline-dropped rows are already counted as dropped;
                 # a row that is both dropped and bad counts once
                 extra["quarantined"] = int((q & live).sum())
                 extra["clipped"] = int((c & live).sum())
-                self._guard.observe(norms[live & ~q],
-                                    quarantined=extra["quarantined"],
-                                    clipped=extra["clipped"])
+                self._guard_obs = (norms[live & ~q], extra["quarantined"],
+                                   extra["clipped"])
             # uplink accounting: bytes are counted when a delta is
             # SHIPPED, regardless of whether the fold uses it — a
             # deadline-dropped client still paid its uplink; a runtime
@@ -1263,21 +1292,19 @@ class FederatedTrainer:
                             else self._edges - edge_down)))
             # train loss over clients whose update ARRIVED (live rows) —
             # identical to the historical mean when nothing timed out
-            losses_h = np.asarray(losses)[:n]
             train_loss = (float(losses_h[live].mean()) if live.any()
                           else 0.0)
         finally:
             # released on error too — leaking the slot would deadlock the
             # NEXT run_round inside the staging ring instead of erroring
             staged.release()
-        return (train_loss, diag, staged.host_seconds,
-                staged.device_seconds, extra)
+        return train_loss, diag, extra
 
     def _run_round_serial(self, t: int):
-        clients = self._sample_clients(t)
-        tic = time.perf_counter()
-        round_batches = self._round_batches(clients, t)
-        ingest = time.perf_counter() - tic
+        with timed("fl.ingest.stage", round=t) as stage:
+            with span("fl.ingest.sample", round=t):
+                clients = self._sample_clients(t)
+            round_batches = self._round_batches(clients, t)
         extra = self.algo.client_extra(self.server_state)
         deltas, losses = [], []
         for (batches, mask) in round_batches:
@@ -1290,7 +1317,11 @@ class FederatedTrainer:
         # transforms the fused round applies, run eagerly on the host-
         # stacked deltas — injection, deadline fold, guard — so the
         # chaos regimes stay cross-checkable against this path too
-        out: Dict[str, Any] = {}
+        out: Dict[str, Any] = {
+            "ingest_host_seconds": stage.seconds,
+            "ingest_stage_seconds": stage.seconds,
+            "steps_useful": sum(int(m.sum()) for _, m in round_batches),
+            "steps_computed": len(round_batches) * self._max_batches}
         cm = None
         n = len(clients)
         live = np.ones(n, bool)
@@ -1350,9 +1381,8 @@ class FederatedTrainer:
             norms = np.asarray(gstats["norm"])
             out["quarantined"] = int((q & live).sum())
             out["clipped"] = int((c & live).sum())
-            self._guard.observe(norms[live & ~q],
-                                quarantined=out["quarantined"],
-                                clipped=out["clipped"])
+            self._guard_obs = (norms[live & ~q], out["quarantined"],
+                               out["clipped"])
         if self._codec_ef:
             # pre-guard decode residual, nonfinite-sanitized, mean over
             # the surviving clients (mirrors the fused round)
@@ -1376,7 +1406,7 @@ class FederatedTrainer:
                         else self._edges - edge_down)))
         losses_h = np.asarray(losses)
         train_loss = float(losses_h[live].mean()) if live.any() else 0.0
-        return train_loss, diag, ingest, 0.0, out
+        return train_loss, diag, out
 
     def _run_round_async(self, t: int):
         """One buffered-async server step: the engine collects the next
@@ -1387,6 +1417,8 @@ class FederatedTrainer:
             t, self.params, self.server_state)
         extra = {"staleness_mean": m["staleness_mean"],
                  "staleness_max": m["staleness_max"],
+                 "ingest_host_seconds": m["host_seconds"],
+                 "ingest_device_seconds": m["device_seconds"],
                  # uplink accounting: updates SHIPPED during this round's
                  # collection — bytes are paid at ship time whether or
                  # not this fold consumed the update (a straggler folds
@@ -1412,10 +1444,9 @@ class FederatedTrainer:
             norms = np.asarray(m["guard_stats"]["norm"])[:nb]
             extra["quarantined"] = int(q.sum())
             extra["clipped"] = int(c.sum())
-            self._guard.observe(norms[~q], quarantined=extra["quarantined"],
-                                clipped=extra["clipped"])
-        return (m["train_loss"], m["diag"], m["host_seconds"],
-                m["device_seconds"], extra)
+            self._guard_obs = (norms[~q], extra["quarantined"],
+                               extra["clipped"])
+        return m["train_loss"], m["diag"], extra
 
     def _resolve_pending_eval(self):
         if self._pending_eval is not None:
@@ -1432,11 +1463,21 @@ class FederatedTrainer:
         # finalize()/run() end)
         if self._pending_eval is not None and self._pending_eval[1].done():
             self._resolve_pending_eval()
-        tic = time.perf_counter()
-        run = (self._run_round_async if self._engine is not None
-               else self._run_round_vectorized if self.cfg.vectorize
-               else self._run_round_serial)
-        train_loss, diag, ingest_host, ingest_dev, extra = run(t)
+        with timed("fl.round", round=t) as whole:
+            run = (self._run_round_async if self._engine is not None
+                   else self._run_round_vectorized if self.cfg.vectorize
+                   else self._run_round_serial)
+            train_loss, diag, extra = run(t)
+            with span("fl.round.record", round=t):
+                rec = self._record_round(t, train_loss, diag, extra)
+        rec.seconds = whole.seconds
+        return rec
+
+    def _record_round(self, t: int, train_loss: float, diag, extra
+                      ) -> RoundRecord:
+        """The host's work after a round's outputs are read back: its
+        RoundRecord, the guard's observation, eval and the health
+        monitor."""
         # supervised-restart accounting (DESIGN.md §12), attributed to
         # the round whose STAGING crashed — the producer stages ahead,
         # so the old cumulative-counter diff charged a crash during
@@ -1447,12 +1488,13 @@ class FederatedTrainer:
             restarts = self._pipeline.restarts_for(t)
             if restarts:
                 extra["ingest_restarts"] = restarts
+        if self._guard_obs is not None:
+            norms, quarantined, clipped = self._guard_obs
+            self._guard_obs = None
+            self._guard.observe(norms, quarantined=quarantined,
+                                clipped=clipped)
         rec = RoundRecord(
             round=t, train_loss=train_loss,
-            seconds=time.perf_counter() - tic,
-            ingest_seconds=ingest_host + ingest_dev,
-            ingest_host_seconds=ingest_host,
-            ingest_device_seconds=ingest_dev,
             diagnostics={k: float(v) for k, v in diag.items()},
             **extra)
         if self.eval_fn and (t % self.cfg.eval_every == 0
@@ -2001,7 +2043,11 @@ class FederatedTrainer:
         self._max_batches = None if mb < 0 else mb
         self._start_round = int(arrays["round"])
         self.schedule = [row for row in np.asarray(arrays["schedule"])]
-        self.history = [RoundRecord(**r) for r in meta["history"]]
+        # older checkpoints carry ingest_seconds, the sum of the two
+        # ingest fields beside it, which RoundRecord no longer holds
+        self.history = [RoundRecord(**{k: v for k, v in r.items()
+                                       if k != "ingest_seconds"})
+                        for r in meta["history"]]
         if meta["sampler"].get("state"):
             self.sampler.load_state_dict(meta["sampler"]["state"])
         if meta.get("sync_runtime", {}).get("state"):

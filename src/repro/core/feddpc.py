@@ -93,17 +93,18 @@ def server_step(state: Dict[str, PyTree], params: PyTree, deltas: PyTree,
         use_kernel = False
     delta_prev = state["delta_prev"]
 
-    # reduction pass: per-client scalars (4 dots each, vmapped over K)
-    coefs, scales, diag = jax.vmap(
-        lambda d: proj.projection_scalars(d, delta_prev, lam))(deltas)
-    if client_mask is None:
-        diag_mean = jnp.mean
-    else:
-        mf = client_mask.astype(jnp.float32)
-        nvalid = jnp.maximum(mf.sum(), 1.0)
-        coefs = coefs * mf
-        scales = scales * mf * (mf.shape[0] / nvalid)
-        diag_mean = lambda x: jnp.sum(x * mf) / nvalid
+    with jax.named_scope("fl.server.reduce"):
+        # reduction pass: per-client scalars (4 dots each, vmapped over K)
+        coefs, scales, diag = jax.vmap(
+            lambda d: proj.projection_scalars(d, delta_prev, lam))(deltas)
+        if client_mask is None:
+            diag_mean = jnp.mean
+        else:
+            mf = client_mask.astype(jnp.float32)
+            nvalid = jnp.maximum(mf.sum(), 1.0)
+            coefs = coefs * mf
+            scales = scales * mf * (mf.shape[0] / nvalid)
+            diag_mean = lambda x: jnp.sum(x * mf) / nvalid
     wgt = (None if staleness_weights is None
            else jnp.asarray(staleness_weights, jnp.float32))
     E = int(edges) if edges is not None and int(edges) > 1 else None
@@ -112,91 +113,97 @@ def server_step(state: Dict[str, PyTree], params: PyTree, deltas: PyTree,
         if k_rows % E:
             raise ValueError(f"edges={E} must divide the cohort rows "
                              f"({k_rows})")
-    if use_kernel:
-        # epilogue pass: residual+scale, client-mean (Eq. 4) AND the param
-        # update fused into ONE grid over the stacked deltas
-        # (kernels/feddpc_project.batched_epilogue) — one HBM pass instead
-        # of K per-client kernel calls + two more full passes. The
-        # buffered-async fold routes to the scatter-accumulate variant,
-        # which applies the staleness discount inside the grid.
-        from repro.kernels.feddpc_project import ops as k_ops
+    with jax.named_scope("fl.server.epilogue"):
+        if use_kernel:
+            # epilogue pass: residual+scale, client-mean (Eq. 4) AND the
+            # param update fused into ONE grid over the stacked deltas
+            # (kernels/feddpc_project.batched_epilogue) — one HBM pass
+            # instead of K per-client kernel calls + two more full
+            # passes. The buffered-async fold routes to the scatter-
+            # accumulate variant, which applies the staleness discount
+            # inside the grid.
+            from repro.kernels.feddpc_project import ops as k_ops
 
-        def kernel_fold(d_slice, enc_slice, c_slice, s_slice, w_slice):
-            if enc_slice is not None and w_slice is None:
-                return k_ops.dequant_batched_server_epilogue(
-                    enc_slice, delta_prev, params, c_slice, s_slice, eta_g)
-            if enc_slice is not None:
-                return k_ops.dequant_buffered_server_fold(
-                    enc_slice, delta_prev, params, c_slice, s_slice,
-                    w_slice, eta_g)
-            if w_slice is None:
-                return k_ops.batched_server_epilogue(
-                    d_slice, delta_prev, params, c_slice, s_slice, eta_g)
-            return k_ops.buffered_server_fold(
-                d_slice, delta_prev, params, c_slice, s_slice, w_slice,
-                eta_g)
+            def kernel_fold(d_slice, enc_slice, c_slice, s_slice, w_slice):
+                if enc_slice is not None and w_slice is None:
+                    return k_ops.dequant_batched_server_epilogue(
+                        enc_slice, delta_prev, params, c_slice, s_slice,
+                        eta_g)
+                if enc_slice is not None:
+                    return k_ops.dequant_buffered_server_fold(
+                        enc_slice, delta_prev, params, c_slice, s_slice,
+                        w_slice, eta_g)
+                if w_slice is None:
+                    return k_ops.batched_server_epilogue(
+                        d_slice, delta_prev, params, c_slice, s_slice,
+                        eta_g)
+                return k_ops.buffered_server_fold(
+                    d_slice, delta_prev, params, c_slice, s_slice, w_slice,
+                    eta_g)
 
-        if E is None:
-            new_params, delta_t = kernel_fold(deltas, encoded, coefs,
-                                              scales, wgt)
+            if E is None:
+                new_params, delta_t = kernel_fold(deltas, encoded, coefs,
+                                                  scales, wgt)
+            else:
+                # two-level fold: each edge runs the SAME fused grid (Pallas
+                # epilogue / codec dequant included) over its k'/E-row slice
+                # — its partial mean is the edge→server summary — and the
+                # server averages the E partials (equal groups: exact)
+                rows = k_rows // E
+                def rsl(tree, lo, hi):
+                    return jax.tree.map(lambda x: x[lo:hi], tree)
+                parts = []
+                for e in range(E):
+                    lo, hi = e * rows, (e + 1) * rows
+                    _, part = kernel_fold(
+                        rsl(deltas, lo, hi),
+                        None if encoded is None else rsl(encoded, lo, hi),
+                        coefs[lo:hi], scales[lo:hi],
+                        None if wgt is None else wgt[lo:hi])
+                    parts.append(part)
+                delta_t = jax.tree.map(
+                    lambda *xs: jnp.mean(jnp.stack(xs), axis=0), *parts)
+                new_params = jax.tree.map(
+                    lambda w, d: (w.astype(jnp.float32)
+                                  - eta_g * d).astype(w.dtype),
+                    params, delta_t)
         else:
-            # two-level fold: each edge runs the SAME fused grid (Pallas
-            # epilogue / codec dequant included) over its k'/E-row slice
-            # — its partial mean is the edge→server summary — and the
-            # server averages the E partials (equal groups: exact)
-            rows = k_rows // E
-            def rsl(tree, lo, hi):
-                return jax.tree.map(lambda x: x[lo:hi], tree)
-            parts = []
-            for e in range(E):
-                lo, hi = e * rows, (e + 1) * rows
-                _, part = kernel_fold(
-                    rsl(deltas, lo, hi),
-                    None if encoded is None else rsl(encoded, lo, hi),
-                    coefs[lo:hi], scales[lo:hi],
-                    None if wgt is None else wgt[lo:hi])
-                parts.append(part)
+            if wgt is not None:
+                scales = scales * wgt
+            def bc(s, x):
+                return s.reshape((-1,) + (1,) * (x.ndim - 1))
+
+            def mean_rows(x):
+                if E is None:
+                    return jnp.mean(x, axis=0)
+                # two-level: per-edge partial means, then the mean of the E
+                # edge summaries — the mask/staleness weights are already
+                # folded into the scales, so the decomposition is exact
+                return jnp.mean(jnp.mean(
+                    x.reshape((E, x.shape[0] // E) + x.shape[1:]), axis=1),
+                    axis=0)
+
+            # scaled residual + mean over the client axis (Eq. 4)
             delta_t = jax.tree.map(
-                lambda *xs: jnp.mean(jnp.stack(xs), axis=0), *parts)
+                lambda d, p: mean_rows(
+                    bc(scales, d) * (d.astype(jnp.float32) - bc(coefs, d)
+                                     * p.astype(jnp.float32)[None])),
+                deltas, delta_prev)
             new_params = jax.tree.map(
                 lambda w, d: (w.astype(jnp.float32)
                               - eta_g * d).astype(w.dtype),
                 params, delta_t)
-    else:
-        if wgt is not None:
-            scales = scales * wgt
-        def bc(s, x):
-            return s.reshape((-1,) + (1,) * (x.ndim - 1))
-
-        def mean_rows(x):
-            if E is None:
-                return jnp.mean(x, axis=0)
-            # two-level: per-edge partial means, then the mean of the E
-            # edge summaries — the mask/staleness weights are already
-            # folded into the scales, so the decomposition is exact
-            return jnp.mean(jnp.mean(
-                x.reshape((E, x.shape[0] // E) + x.shape[1:]), axis=1),
-                axis=0)
-
-        # scaled residual + mean over the client axis (Eq. 4)
-        delta_t = jax.tree.map(
-            lambda d, p: mean_rows(
-                bc(scales, d) * (d.astype(jnp.float32)
-                                 - bc(coefs, d) * p.astype(jnp.float32)[None])),
-            deltas, delta_prev)
-        new_params = jax.tree.map(
-            lambda w, d: (w.astype(jnp.float32) - eta_g * d).astype(w.dtype),
-            params, delta_t)
-    new_state = {"delta_prev": delta_t}
-    diagnostics = {
-        "mean_coef": diag_mean(diag["coef"]),
-        "mean_cos_angle": diag_mean(diag["cos_angle"]),
-        "mean_scale": diag_mean(diag["scale"]),
-        "mean_norm_delta": diag_mean(diag["norm_delta"]),
-        "norm_global_update": proj.tree_norm(delta_t),
-        # orthogonality invariant: <Delta_t, Delta_{t-1}> ~ 0 after round 1
-        "global_dot_prev": proj.tree_vdot(delta_t, delta_prev),
-    }
+        new_state = {"delta_prev": delta_t}
+        diagnostics = {
+            "mean_coef": diag_mean(diag["coef"]),
+            "mean_cos_angle": diag_mean(diag["cos_angle"]),
+            "mean_scale": diag_mean(diag["scale"]),
+            "mean_norm_delta": diag_mean(diag["norm_delta"]),
+            "norm_global_update": proj.tree_norm(delta_t),
+            # orthogonality invariant: <Delta_t, Delta_{t-1}> ~ 0 after
+            # round 1
+            "global_dot_prev": proj.tree_vdot(delta_t, delta_prev),
+        }
     return new_params, new_state, diagnostics
 
 

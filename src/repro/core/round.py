@@ -268,7 +268,9 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
         ef = next(it) if ef_active else None
         opt_state = next(it) if server_opt is not None else None
         extra = algo.client_extra(server_state)
-        deltas, losses = local(params, batches, masks, extra)
+        # stage scopes (repro.trace): HLO op_name metadata only
+        with jax.named_scope("fl.local"):
+            deltas, losses = local(params, batches, masks, extra)
         if inject_faults:
             deltas = apply_fault_codes(deltas, fault_codes, fault_magnitude)
         if real_clients is not None:
@@ -292,44 +294,48 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
             # error-feedback residual (broadcast compensation — the same
             # accumulator is folded into every row, so a mean-style rule
             # recovers the compensation exactly in aggregate)
-            shipped = deltas
-            if ef_active:
-                shipped = jax.tree.map(
-                    lambda d, e: (d.astype(jnp.float32)
-                                  + e.astype(jnp.float32)[None]
-                                  ).astype(d.dtype), deltas, ef)
-            payload = codec.encode_cohort(shipped, key=codec_key)
-            decoded = codec.decode_cohort(payload)
-            deltas = decoded
+            with jax.named_scope("fl.codec"):
+                shipped = deltas
+                if ef_active:
+                    shipped = jax.tree.map(
+                        lambda d, e: (d.astype(jnp.float32)
+                                      + e.astype(jnp.float32)[None]
+                                      ).astype(d.dtype), deltas, ef)
+                payload = codec.encode_cohort(shipped, key=codec_key)
+                decoded = codec.decode_cohort(payload)
+                deltas = decoded
         gstats = None
         if guard:
             # quarantine/clip decisions read the DECODED (quantized-
             # domain) deltas — the values the server would aggregate
-            deltas, client_ids, cm, gstats = apply_guard(
-                deltas, client_ids, cm, guard_thresh, guard_cfg)
+            with jax.named_scope("fl.guard"):
+                deltas, client_ids, cm, gstats = apply_guard(
+                    deltas, client_ids, cm, guard_thresh, guard_cfg)
         new_ef = None
         if ef_active:
             # residual of the PRE-guard decode (pure quantization error;
             # a clipped row's shaved mass is a guard decision, not lost
             # signal), nonfinite-sanitized so faulty rows cannot poison
             # the accumulator, mean over the surviving clients only
-            resid = codec_base.sanitized_residual(shipped, decoded)
-            new_ef = proj.masked_client_mean(resid, cm)
+            with jax.named_scope("fl.codec"):
+                resid = codec_base.sanitized_residual(shipped, decoded)
+                new_ef = proj.masked_client_mean(resid, cm)
         # the fused dequant epilogue re-derives the decoded rows from the
         # payload scalars, so it is only handed over when the guard has
         # NOT rewritten rows between decode and aggregation
-        new_params, new_state, diag = algo.step(
-            server_state, params, deltas, client_ids, eta_g, 0,
-            client_mask=cm, partitioned=partitioned,
-            encoded=(payload if codec_lossy and not guard else None),
-            edges=edges)
-        new_opt = None
-        if server_opt is not None:
-            # adaptive server step (DESIGN.md §14): precondition the
-            # POST-projection aggregate — re-step from the round's
-            # incoming params with moment-scaled magnitudes
-            new_params, new_opt = server_opt.apply(params, new_params,
-                                                   opt_state)
+        with jax.named_scope("fl.server"):
+            new_params, new_state, diag = algo.step(
+                server_state, params, deltas, client_ids, eta_g, 0,
+                client_mask=cm, partitioned=partitioned,
+                encoded=(payload if codec_lossy and not guard else None),
+                edges=edges)
+            new_opt = None
+            if server_opt is not None:
+                # adaptive server step (DESIGN.md §14): precondition the
+                # POST-projection aggregate — re-step from the round's
+                # incoming params with moment-scaled magnitudes
+                new_params, new_opt = server_opt.apply(params, new_params,
+                                                       opt_state)
         outs = [new_params, new_state, losses, diag]
         if guard:
             outs.append(gstats)

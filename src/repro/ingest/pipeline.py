@@ -22,6 +22,14 @@ keeps placement on the consumer thread, where it is measured as
 RoundRecord.ingest_device_seconds — the historical "transfer at
 dispatch" cost the two knobs exist to remove.
 
+Each stage is a host span (repro.trace, DESIGN.md §16) carrying the
+round it stages: ``fl.ingest.stage`` around the producer's whole work for
+a round, with ``fl.ingest.sample``, ``fl.ingest.read`` (the gathers: the
+read stage drains each client's batch iterator), ``fl.ingest.stack`` and
+``fl.ingest.place`` inside it; ``fl.ingest.wait`` around the consumer's
+wait on the ring, and ``fl.ingest.place`` on the consumer's thread where
+placement runs there.
+
 Client SAMPLING stays inside ``sample_fn`` (the trainer's, which
 snapshots pre-draw RNG/sampler state for checkpointing): the pipeline
 calls it in round order from whichever thread stages the round, so a
@@ -39,6 +47,7 @@ from repro.ingest.placement import CohortPlacer
 from repro.ingest.prefetch import CohortPrefetcher
 from repro.ingest.sources import DataSource
 from repro.ingest.stack import stack_cohort_into
+from repro.trace import span, timed
 
 PyTree = Any
 
@@ -48,7 +57,9 @@ class StagedCohort:
     """One round's fully staged inputs + the consumer-side waits that
     produced them. ``batches``/``masks``/``ids`` are committed device
     values ready for zero-copy dispatch; ``clients`` is the UNPADDED
-    sampled cohort (host), for loss masking and the schedule.
+    sampled cohort (host), for loss masking and the schedule. The
+    seconds are the durations of the round's ingest spans, and
+    ``record_fields()`` hands them, with the counters, to RoundRecord.
 
     ``release()`` returns the staging buffer to the ring and MUST be
     called (idempotent; a try/finally around the round's dispatch+sync)
@@ -62,7 +73,22 @@ class StagedCohort:
     ids: Any
     host_seconds: float = 0.0      # blocked on sample+read+stack staging
     device_seconds: float = 0.0    # blocked on H2D placement at dispatch
+    stage_seconds: float = 0.0     # fl.ingest.stage, on the staging thread
+    place_seconds: float = 0.0     # fl.ingest.place, on whichever thread
+    ready: int = 0                 # staged rounds waiting when asked for
+    steps_useful: int = 0          # unmasked (client, step) slots
+    steps_computed: int = 0        # all slots of the staged mask
     _release: Optional[Callable[[], None]] = field(default=None, repr=False)
+
+    def record_fields(self) -> dict:
+        """The RoundRecord fields this round's ingest fills."""
+        return {"ingest_host_seconds": self.host_seconds,
+                "ingest_device_seconds": self.device_seconds,
+                "ingest_stage_seconds": self.stage_seconds,
+                "ingest_place_seconds": self.place_seconds,
+                "ingest_ready": self.ready,
+                "steps_useful": self.steps_useful,
+                "steps_computed": self.steps_computed}
 
     def release(self):
         if self._release is not None:
@@ -151,8 +177,9 @@ class CohortIngestPipeline:
     def client_lists(self, clients: Sequence[int], t: int):
         """READ (+decode) stage: materialize each sampled client's
         batches for round t and grow the M bucket to the cohort max."""
-        per_client = [list(self.source.client_batches(int(c), t))
-                      for c in clients]
+        with span("fl.ingest.read", round=t):
+            per_client = [list(self.source.client_batches(int(c), t))
+                          for c in clients]
         mx = max(len(b) for b in per_client)
         if self._max_batches is None or mx > self._max_batches:
             self._max_batches = mx      # grow-once; keeps jit cache small
@@ -169,7 +196,7 @@ class CohortIngestPipeline:
                               self.num_clients, np.int32)])
         return ids
 
-    def _stage_host(self, t: int, slot: dict):
+    def _stage_host(self, t: int, slot: dict) -> StagedCohort:
         """sample -> read -> stack into the slot's buffers.
 
         The drawn cohort is cached per round until staging SUCCEEDS: a
@@ -179,7 +206,8 @@ class CohortIngestPipeline:
         if t in self._sampled:
             clients = self._sampled[t]
         else:
-            clients = self.sample_fn(t)
+            with span("fl.ingest.sample", round=t):
+                clients = self.sample_fn(t)
             self._sampled[t] = clients
         if self.local_rows is not None:
             # multi-process: the full schedule was drawn (identically on
@@ -198,14 +226,25 @@ class CohortIngestPipeline:
                 self._sync_calls[t] = n + 1
                 self._max_batches = int(self.sync_max_batches(
                     f"{t}.{n}", int(self._max_batches)))
-            batches, masks = stack_cohort_into(
-                lists, self._max_batches, slot, pad_to=hi - lo)
-            return (clients, batches, masks,
-                    self._pad_ids(clients)[lo:hi])
-        lists = self.client_lists(clients, t)
-        batches, masks = stack_cohort_into(lists, self._max_batches, slot,
-                                           pad_to=self.pad_to)
-        return clients, batches, masks, self._pad_ids(clients)
+            pad_to, ids = hi - lo, self._pad_ids(clients)[lo:hi]
+        else:
+            lists = self.client_lists(clients, t)
+            pad_to, ids = self.pad_to, self._pad_ids(clients)
+        with span("fl.ingest.stack", round=t):
+            batches, masks = stack_cohort_into(lists, self._max_batches,
+                                               slot, pad_to=pad_to)
+        # this process's rows of the staged mask, counted on the host
+        return StagedCohort(t, clients, batches, masks, ids,
+                            steps_useful=int(masks.sum()),
+                            steps_computed=int(masks.size))
+
+    def _place(self, staged: StagedCohort) -> float:
+        """DEVICE-PLACE stage, in place; returns its seconds."""
+        with timed("fl.ingest.place", round=staged.round) as place:
+            staged.batches, staged.masks, staged.ids = self.placer.place(
+                staged.batches, staged.masks, staged.ids)
+        staged.place_seconds = place.seconds
+        return place.seconds
 
     def _produce(self, t: int, slot: dict):
         """Ring-producer body. In device-staged mode the place stage
@@ -219,12 +258,14 @@ class CohortIngestPipeline:
             raise RuntimeError(
                 f"injected ingest producer crash at round {t} "
                 f"(attempt {attempt})")
-        clients, batches, masks, ids = self._stage_host(t, slot)
-        if self.device_stage:
-            batches, masks, ids = self.placer.place(batches, masks, ids)
+        with timed("fl.ingest.stage", round=t) as stage:
+            staged = self._stage_host(t, slot)
+            if self.device_stage:
+                self._place(staged)
+        staged.stage_seconds = stage.seconds
         self._sampled.pop(t, None)
         self._attempts.pop(t, None)
-        return clients, batches, masks, ids
+        return staged
 
     def get(self, t: int) -> StagedCohort:
         """Prefetching consumer: blocks only until round t is staged
@@ -237,55 +278,51 @@ class CohortIngestPipeline:
                                           stall_timeout=self.stall_timeout,
                                           max_restarts=self.max_restarts,
                                           restart_backoff=self.restart_backoff)
-        tic = time.perf_counter()
-        (clients, batches, masks, ids), slot = self._ring.get(t)
-        host_s = time.perf_counter() - tic
-        dev_s = 0.0
+        ready = self._ring.pending()
+        with timed("fl.ingest.wait", round=t) as wait:
+            staged, slot = self._ring.get(t)
+        staged.host_seconds, staged.ready = wait.seconds, ready
         if not self.device_stage:
             try:
-                tic = time.perf_counter()
-                batches, masks, ids = self.placer.place(batches, masks, ids)
-                dev_s = time.perf_counter() - tic
+                staged.device_seconds = self._place(staged)
             except BaseException:
                 # failed placement cannot leak the slot — that would
                 # deadlock the NEXT get() instead of erroring
                 self._ring.release(slot)
                 raise
-        return StagedCohort(t, clients, batches, masks, ids, host_s, dev_s,
-                            _release=lambda: self._ring.release(slot))
+        staged._release = lambda: self._ring.release(slot)
+        return staged
 
     def stage_blocking(self, t: int) -> StagedCohort:
         """Non-prefetching path: stage round t inline on the caller's
         thread (out-of-order rounds allowed). Reuses one private slot —
         valid because the caller synchronizes each round before staging
         the next (release() is a no-op here)."""
-        tic = time.perf_counter()
-        while True:
-            try:
-                attempt = self._attempts.get(t, 0)
-                self._attempts[t] = attempt + 1
-                if (self._crash_hook is not None
-                        and self._crash_hook(t, attempt)):
-                    raise RuntimeError(
-                        f"injected ingest producer crash at round {t} "
-                        f"(attempt {attempt})")
-                clients, batches, masks, ids = self._stage_host(
-                    t, self._blocking_slot)
-                break
-            except BaseException:
-                if self._blocking_restarts >= self.max_restarts:
-                    raise
-                self._blocking_restarts += 1
-                self._blocking_rounds[t] = self._blocking_rounds.get(t, 0) + 1
-                if self.restart_backoff > 0:
-                    time.sleep(self.restart_backoff * (2 ** attempt))
+        with timed("fl.ingest.stage", round=t) as stage:
+            while True:
+                try:
+                    attempt = self._attempts.get(t, 0)
+                    self._attempts[t] = attempt + 1
+                    if (self._crash_hook is not None
+                            and self._crash_hook(t, attempt)):
+                        raise RuntimeError(
+                            f"injected ingest producer crash at round {t} "
+                            f"(attempt {attempt})")
+                    staged = self._stage_host(t, self._blocking_slot)
+                    break
+                except BaseException:
+                    if self._blocking_restarts >= self.max_restarts:
+                        raise
+                    self._blocking_restarts += 1
+                    self._blocking_rounds[t] = (
+                        self._blocking_rounds.get(t, 0) + 1)
+                    if self.restart_backoff > 0:
+                        time.sleep(self.restart_backoff * (2 ** attempt))
         self._sampled.pop(t, None)
         self._attempts.pop(t, None)
-        host_s = time.perf_counter() - tic
-        tic = time.perf_counter()
-        batches, masks, ids = self.placer.place(batches, masks, ids)
-        dev_s = time.perf_counter() - tic
-        return StagedCohort(t, clients, batches, masks, ids, host_s, dev_s)
+        staged.host_seconds = staged.stage_seconds = stage.seconds
+        staged.device_seconds = self._place(staged)
+        return staged
 
     # ---- lifecycle ----
 

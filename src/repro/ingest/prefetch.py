@@ -178,6 +178,11 @@ class CohortPrefetcher:
             return ""
         return "\nproducer traceback:\n" + self._exc_tb
 
+    def pending(self) -> int:
+        """Staged rounds waiting in the ring: 0 means the next ``get``
+        waits on the producer."""
+        return self._ready.qsize()
+
     def release(self, slot: dict):
         self._free.put(slot)
 
