@@ -383,6 +383,14 @@ class RoundRecord:
     # round computes (this process's rows; 0 in the async regime)
     steps_useful: int = 0
     steps_computed: int = 0
+    # client-steps the round program computes: with the active-width
+    # scan (core/client.py) the sum over non-empty steps of each step's
+    # width; every slot where the client axis is sharded
+    steps_run: int = 0
+    # bytes the staged round placed on the device: the pixel stack, or
+    # the sample indices where the index form gathers from a device
+    # table (DESIGN.md §10); masks and ids included
+    ingest_placed_bytes: int = 0
     diagnostics: Dict[str, float] = field(default_factory=dict)
     # buffered-async regime only (DESIGN.md §11): staleness statistics
     # of the arrivals this server step folded — kept OUT of diagnostics
@@ -832,7 +840,11 @@ class FederatedTrainer:
             max_restarts=exec_cfg.ingest_max_restarts,
             restart_backoff=exec_cfg.ingest_restart_backoff_s,
             crash_hook=(fault_plan.ingest_crash
-                        if fault_plan is not None else None))
+                        if fault_plan is not None else None),
+            # the client-steps the round program's local scan computes
+            steps_run=(client_mod.steps_run
+                       if self.mesh is None or self.mesh.devices.size == 1
+                       else None))
         # buffered-async engine (DESIGN.md §11): owns the virtual-time
         # wave heap; the runtime model's draws ride the sampling lock.
         # A round deadline WITHOUT async_buffer also needs a runtime
@@ -893,12 +905,13 @@ class FederatedTrainer:
         from repro.core import projection as proj
         from repro.core.async_engine import BufferedAsyncEngine
         from repro.core.baselines import client_kwargs
-        local = client_mod.make_cohort_local_update(
-            loss_fn, algo_cfg.eta_l, variant=self.algo.client_variant,
-            optimizer=algo_cfg.local_optimizer, **client_kwargs(self.algo))
-        algo, eta_g = self.algo, algo_cfg.eta_g
         partitioned = bool(self.mesh is not None
                            and self.mesh.devices.size > 1)
+        local = client_mod.make_cohort_local_update(
+            loss_fn, algo_cfg.eta_l, variant=self.algo.client_variant,
+            optimizer=algo_cfg.local_optimizer,
+            clients_sharded=partitioned, **client_kwargs(self.algo))
+        algo, eta_g = self.algo, algo_cfg.eta_g
         # codec stage (repro.codec, DESIGN.md §13): the wave ENCODES its
         # cohort before the entries hit the arrival heap — in-flight and
         # checkpointed entries carry the quantized wire payload, and the
@@ -1321,7 +1334,8 @@ class FederatedTrainer:
             "ingest_host_seconds": stage.seconds,
             "ingest_stage_seconds": stage.seconds,
             "steps_useful": sum(int(m.sum()) for _, m in round_batches),
-            "steps_computed": len(round_batches) * self._max_batches}
+            "steps_computed": len(round_batches) * self._max_batches,
+            "steps_run": len(round_batches) * self._max_batches}
         cm = None
         n = len(clients)
         live = np.ones(n, bool)

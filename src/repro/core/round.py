@@ -182,8 +182,12 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
     and feeds accepted norms back into the rolling threshold window.
 
     batches: pytree with leading axes (K, M, ...) — K participating
-    clients, M padded minibatches each; masks (K, M) bool marks the valid
-    ones (None = all valid, skipping the masked-select pass entirely).
+    clients, M padded minibatches each — or an ``IndexedBatches`` (a
+    device sample table and (K, M, B) sample indices, gathered step by
+    step; DESIGN.md §10); masks (K, M) bool marks the valid ones (None =
+    all valid, skipping the masked-select pass entirely). With masks and
+    no partitioning, local training runs the active-width scan
+    (core/client.py): each step computes only the rows it needs.
     client_ids (K,) int32 feeds stateful server rules (FedVARP's
     per-client table). The server-side ``extra`` (Delta_prev for the
     cm/ga client variants) is derived from server_state INSIDE the
@@ -242,13 +246,15 @@ def make_cohort_round(loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
     anything the algorithm leaves unset keeps the local-update builder's
     defaults.
     """
-    local = client_mod.make_cohort_local_update(
-        loss_fn, eta_l, variant=algo.client_variant, optimizer=optimizer,
-        **client_kwargs(algo))
     # any mesh of more than one device partitions the round under GSPMD,
     # which cannot partition a Mosaic kernel: the server rule then takes
-    # its jnp epilogue (DESIGN.md §2)
+    # its jnp epilogue, and local training keeps the full-width masked
+    # scan, since the active-width scan's row gather would move weights
+    # between chips (DESIGN.md §2)
     partitioned = bool(mesh is not None and mesh.devices.size > 1)
+    local = client_mod.make_cohort_local_update(
+        loss_fn, eta_l, variant=algo.client_variant, optimizer=optimizer,
+        clients_sharded=partitioned, **client_kwargs(algo))
     # codec stage (repro/codec, DESIGN.md §13): only LOSSY codecs enter
     # the program — identity is a literal pass-through and compiles to
     # the no-codec round. Error feedback adds one params-shaped f32

@@ -94,7 +94,10 @@ class StreamingImageSource(DataSource):
     staging thread, so shard gathering overlaps device compute.
 
     ``client_weights()`` exposes shard sizes for ``WeightedSampler``
-    (participation proportional to data size)."""
+    (participation proportional to data size). Every batch is a plain
+    selection of the training arrays, so the source offers the index
+    form (``sample_table`` / ``client_selections``, ingest/sources.py)
+    for a round that gathers on the device."""
 
     def __init__(self, data: FederatedImageData, batch_size: int,
                  local_epochs: int = 1):
@@ -105,6 +108,15 @@ class StreamingImageSource(DataSource):
     def client_batches(self, client: int, round: int):
         return client_batches(self.data, client, self.batch_size, round,
                               self.local_epochs)
+
+    def sample_table(self) -> Dict[str, np.ndarray]:
+        return {"images": self.data.train_images,
+                "labels": self.data.train_labels}
+
+    def client_selections(self, client: int, round: int) -> List[np.ndarray]:
+        return [sel.astype(np.int32) for sel, _ in iter_batch_selections(
+            self.data.client_indices[client], self.batch_size, client,
+            round, self.local_epochs)]
 
     def client_weights(self) -> np.ndarray:
         return np.asarray([len(ix) for ix in self.data.client_indices],

@@ -30,6 +30,17 @@ read stage drains each client's batch iterator), ``fl.ingest.stack`` and
 wait on the ring, and ``fl.ingest.place`` on the consumer's thread where
 placement runs there.
 
+INDEX INGEST: where the round runs on one device (no input sharding,
+no ``local_rows``) and the source offers the index form
+(``sample_table`` / ``client_selections``, ingest/sources.py), the
+first staged round places the source's table on the device, without
+waiting for the copy, which overlaps the round's first compile. Each
+round then reads and stacks the clients' (B,) sample indices instead of
+their pixels — the same padding rules — places the small (K, M, B)
+int32 stack, and hands the round an ``IndexedBatches``
+(ingest/stack.py), whose batches the round program gathers on the
+device. The batch values are the pixel path's, bit for bit.
+
 Client SAMPLING stays inside ``sample_fn`` (the trainer's, which
 snapshots pre-draw RNG/sampler state for checkpointing): the pipeline
 calls it in round order from whichever thread stages the round, so a
@@ -41,12 +52,13 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.ingest.placement import CohortPlacer
 from repro.ingest.prefetch import CohortPrefetcher
 from repro.ingest.sources import DataSource
-from repro.ingest.stack import stack_cohort_into
+from repro.ingest.stack import IndexedBatches, stack_cohort_into
 from repro.trace import span, timed
 
 PyTree = Any
@@ -78,6 +90,8 @@ class StagedCohort:
     ready: int = 0                 # staged rounds waiting when asked for
     steps_useful: int = 0          # unmasked (client, step) slots
     steps_computed: int = 0        # all slots of the staged mask
+    steps_run: int = 0             # client-steps the round computes
+    placed_bytes: int = 0          # bytes this round places on device
     _release: Optional[Callable[[], None]] = field(default=None, repr=False)
 
     def record_fields(self) -> dict:
@@ -88,7 +102,9 @@ class StagedCohort:
                 "ingest_place_seconds": self.place_seconds,
                 "ingest_ready": self.ready,
                 "steps_useful": self.steps_useful,
-                "steps_computed": self.steps_computed}
+                "steps_computed": self.steps_computed,
+                "steps_run": self.steps_run,
+                "ingest_placed_bytes": self.placed_bytes}
 
     def release(self):
         if self._release is not None:
@@ -115,6 +131,9 @@ class CohortIngestPipeline:
     host-side. ``sync_max_batches(tag, m) -> m'`` (launch/distributed.
     kv_allmax under a per-round tag) keeps the grow-once M bucket — and
     with it the stacked shape and jit signature — agreed across hosts.
+
+    ``steps_run(mask) -> int`` counts the client-steps the round program
+    computes for a staged (K, M) mask (default: every slot).
     """
 
     def __init__(self, source: DataSource,
@@ -127,7 +146,8 @@ class CohortIngestPipeline:
                  sync_max_batches: Optional[Callable[[str, int], int]] = None,
                  stall_timeout: Optional[float] = None,
                  max_restarts: int = 0, restart_backoff: float = 0.05,
-                 crash_hook: Optional[Callable[[int, int], bool]] = None):
+                 crash_hook: Optional[Callable[[int, int], bool]] = None,
+                 steps_run: Optional[Callable[[np.ndarray], int]] = None):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self.source = source
@@ -161,6 +181,13 @@ class CohortIngestPipeline:
         self._max_batches: Optional[int] = None
         self._ring: Optional[CohortPrefetcher] = None
         self._blocking_slot: dict = {}   # stage_blocking's private buffer
+        self._steps_run = steps_run or (lambda mask: int(mask.size))
+        # the index form's table (module docstring), and its device copy
+        # once the first staged round has placed it
+        self._host_table = (source.sample_table() if local_rows is None
+                            and self.placer.input_sharding is None
+                            else None)
+        self._table = None
 
     # ---- shared read stage / shape bucket ----
 
@@ -174,12 +201,20 @@ class CohortIngestPipeline:
     def max_batches(self, value: Optional[int]):
         self._max_batches = value
 
-    def client_lists(self, clients: Sequence[int], t: int):
+    @property
+    def indexed(self) -> bool:
+        """True where rounds are staged as sample indices."""
+        return self._host_table is not None
+
+    def client_lists(self, clients: Sequence[int], t: int,
+                     indices: bool = False):
         """READ (+decode) stage: materialize each sampled client's
-        batches for round t and grow the M bucket to the cohort max."""
+        batches for round t — or, with ``indices``, each batch's sample
+        indices — and grow the M bucket to the cohort max."""
+        read = (self.source.client_selections if indices
+                else self.source.client_batches)
         with span("fl.ingest.read", round=t):
-            per_client = [list(self.source.client_batches(int(c), t))
-                          for c in clients]
+            per_client = [list(read(int(c), t)) for c in clients]
         mx = max(len(b) for b in per_client)
         if self._max_batches is None or mx > self._max_batches:
             self._max_batches = mx      # grow-once; keeps jit cache small
@@ -220,7 +255,7 @@ class CohortIngestPipeline:
                     f"host rows [{lo}, {hi}) hold no real clients of the "
                     f"{k}-client cohort — every host needs at least one; "
                     "use fewer processes or a larger cohort")
-            lists = self.client_lists(local, t)
+            lists = self.client_lists(local, t, self.indexed)
             if self.sync_max_batches is not None:
                 n = self._sync_calls.get(t, 0)
                 self._sync_calls[t] = n + 1
@@ -228,7 +263,7 @@ class CohortIngestPipeline:
                     f"{t}.{n}", int(self._max_batches)))
             pad_to, ids = hi - lo, self._pad_ids(clients)[lo:hi]
         else:
-            lists = self.client_lists(clients, t)
+            lists = self.client_lists(clients, t, self.indexed)
             pad_to, ids = self.pad_to, self._pad_ids(clients)
         with span("fl.ingest.stack", round=t):
             batches, masks = stack_cohort_into(lists, self._max_batches,
@@ -236,13 +271,22 @@ class CohortIngestPipeline:
         # this process's rows of the staged mask, counted on the host
         return StagedCohort(t, clients, batches, masks, ids,
                             steps_useful=int(masks.sum()),
-                            steps_computed=int(masks.size))
+                            steps_computed=int(masks.size),
+                            steps_run=self._steps_run(masks))
 
     def _place(self, staged: StagedCohort) -> float:
         """DEVICE-PLACE stage, in place; returns its seconds."""
+        staged.placed_bytes = int(sum(
+            np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(
+                (staged.batches, staged.masks, staged.ids))))
         with timed("fl.ingest.place", round=staged.round) as place:
             staged.batches, staged.masks, staged.ids = self.placer.place(
                 staged.batches, staged.masks, staged.ids)
+        if self.indexed:
+            if self._table is None:
+                # not waited for: the first round's compile overlaps it
+                self._table = jax.device_put(self._host_table)
+            staged.batches = IndexedBatches(self._table, staged.batches)
         staged.place_seconds = place.seconds
         return place.seconds
 
@@ -355,3 +399,4 @@ class CohortIngestPipeline:
         share one across trainers) and is never closed here."""
         if self._ring is not None:
             self._ring.stop()
+        self._table = None

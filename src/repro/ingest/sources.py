@@ -14,6 +14,12 @@ Protocol:
         (numpy leaves; every batch of a client/round has the same
         shapes, and shapes are shared across clients so cohorts stack)
     source.close()    release any underlying readers (optional)
+    source.sample_table() -> {key: (N, ...) array} or None (optional)
+    source.client_selections(client, round) -> list of (B,) int arrays
+        the index form: where a source's every batch is
+        ``{key: table[key][sel]}``, it hands out the table and the
+        selections, and a single-device round gathers its batches on
+        the device from a table placed once (DESIGN.md §10)
 
 Sources are CALLER-owned: sweeps share one source across many trainers
 (benchmarks/common.py), so ``FederatedTrainer.close()`` never calls
@@ -36,6 +42,15 @@ class DataSource:
     """Protocol + base class: subclass and implement ``client_batches``."""
 
     def client_batches(self, client: int, round: int) -> Iterable[Batch]:
+        raise NotImplementedError
+
+    def sample_table(self):
+        """The sample table every batch indexes, or None: this source's
+        batches are not plain selections of one table (the default)."""
+        return None
+
+    def client_selections(self, client: int, round: int) -> List[Any]:
+        """Each batch's sample indices into ``sample_table()``."""
         raise NotImplementedError
 
     def close(self) -> None:

@@ -5,11 +5,25 @@ from core/client.py, which keeps deprecated shims for one release).
 stack the fused round consumes; ``stack_cohort_into`` does the same into
 preallocated buffers owned by a staging-ring slot, so the per-round
 np.stack allocations disappear from the ingest path.
+
+``IndexedBatches`` is the index form of a cohort stack: the source's
+sample table, placed on the device once, and the (K, M, B) int32 sample
+indices the same stacking stage builds from each client's batch
+selections; the round program gathers the batches (DESIGN.md §10).
 """
 from __future__ import annotations
 
+from typing import Any, NamedTuple
+
 import jax
 import numpy as np
+
+
+class IndexedBatches(NamedTuple):
+    """A cohort's batches as sample indices into a device table:
+    batch (k, s) is ``{key: table[key][idx[k, s]]}``."""
+    table: Any      # {key: (N, ...)} device arrays, placed once
+    idx: Any        # (K, M, B) int32
 
 
 def stack_batches(batch_list, max_batches: int, template=None):
