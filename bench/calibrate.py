@@ -78,9 +78,7 @@ def main(argv=None, root: Path = ROOT, allow_cpu: bool = False) -> int:
     harness.setup_paths(root)
     import jax
     harness.check_devices(jax, cell["chips"], allow_cpu)
-    import traffic as traffic_mod
-    traffic = traffic_mod.Traffic.load(cell["traffic_path"])
-    task_mod = harness.load_module(cell["task_path"], "task")
+    task_mod, traffic = harness.load_task(cell)
     out_dir = root / "chiprun_out"
     sink = (open(out_dir / f"calibrate-{args.workload}.jsonl", "a")
             if out_dir.is_dir() else None)

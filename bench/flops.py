@@ -14,7 +14,11 @@ FLOP/s utilisation is a share of the matrix units' peak.
 ``epilogue_bytes`` is what FedDPC's server epilogue has to move in one
 round whatever implements it: read the K client pseudo-gradients, the
 previous global update and the weights, and write the weights and the
-new global update, all float32.
+new global update, all float32. ``epilogue_flops`` is what it has to
+compute once the per-client scalars are known: for each client and
+weight the residual d - c * prev (two operations), its scale and the
+sum over clients (two more); then the mean and the step, w - eta_g *
+mean (three).
 """
 from __future__ import annotations
 
@@ -77,3 +81,7 @@ def train_flops_per_sample(model: Dict[str, Any]) -> int:
 
 def epilogue_bytes(num_params: int, clients: int) -> int:
     return (clients + 4) * num_params * F32
+
+
+def epilogue_flops(num_params: int, clients: int) -> int:
+    return (4 * clients + 3) * num_params

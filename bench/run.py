@@ -5,11 +5,33 @@
 
 Everything is found by name. The cell is an entry of ``workloads`` in
 ``BENCHMARK.json``; its configuration is ``bench/configs/<config>.json``,
-which names its task builder ``bench/tasks/<task>.py``; its traffic is
+which names its task ``bench/tasks/<task>.py``; its traffic is
 ``bench/traffic/<traffic>.json``; the limits of its correctness check
 are ``bench/workloads/<cell>.json``; each per-layer metric is read by
-``bench/layer_metrics/<metric>.py``. A new configuration, cell or
-metric is new files, and no edit here.
+``bench/layer_metrics/<metric>.py``. A new configuration, cell, metric
+or task is new files, and no edit here.
+
+A task module holds what is particular to a task's data and kernels:
+
+* ``TRAFFIC_FIELDS``: ``{field: type}``, the traffic file's fields of
+  the task's own data, besides the fields every cell has
+  (``traffic.Traffic``); they are loaded, strictly, into
+  ``Traffic.data``. A module without it declares none.
+* ``build(config, traffic, seed)``: the run's federated job, an object
+  with ``chips_used``, ``run_round(t)``, ``useful_steps(t)``,
+  ``computed_steps()``, ``samples_per_step``, ``flops_per_sample``,
+  ``epilogue_bytes()``, ``kernels()`` (kernel name -> a test on a
+  trace's op event name), ``memory_note()``, ``program()``,
+  ``close()``, ``reference()`` and ``compare(program, reference)``,
+  and optionally with
+
+  - ``work(rounds)``: ``{kernel or scope: {"flops": n, "bytes": n}}``,
+    what the listed rounds require, counted from shapes and the
+    benchmark's own batch rule; a reader finds it for the traced
+    window's rounds as ``ctx["work"]``;
+  - ``scopes()``: program scope -> a test on a trace's op event name
+    (``tracing.scope_tests``); a reader finds each scope's device
+    seconds over the traced window as ``ctx["scope_s"]``.
 
 A run: set-up (data and weights from the seed, the trainer, the shape
 bucket fixed, the first three rounds, which compile or load the round
@@ -78,6 +100,16 @@ def find_cell(name: str, root: Path = ROOT) -> Dict[str, Any]:
     cell["per_layer"] = [m for m in spec["per_layer"]
                          if name in m.get("workloads", [name])]
     return cell
+
+
+def load_task(cell: Dict[str, Any]):
+    """The cell's task module, and its traffic loaded with the fields
+    that the task declares."""
+    import traffic as traffic_mod
+    task_mod = load_module(cell["task_path"],
+                           f"task_{cell['config_data']['task']}")
+    return task_mod, traffic_mod.Traffic.load(
+        cell["traffic_path"], getattr(task_mod, "TRAFFIC_FIELDS", {}))
 
 
 def layer_readers(names: List[str], root: Path = ROOT):
@@ -154,11 +186,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     devices = check_devices(jax, cell["chips"], allow_cpu)
     counter = CompileCounter(jax)
 
-    import traffic as traffic_mod
     from peaks import peaks as peak_table
-    traffic = traffic_mod.Traffic.load(cell["traffic_path"])
     config = cell["config_data"]
-    task_mod = load_module(cell["task_path"], f"task_{config['task']}")
+    task_mod, traffic = load_task(cell)
     log = lambda *a: print(*a, file=sys.stderr, flush=True)   # noqa: E731
 
     # ---- set-up: data, weights, trainer, the first three rounds ----
@@ -168,7 +198,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                       f"asks for {cell['chips']}")
     for t in range(3):
         task.run_round(t)
-    t = 3
+    t = first = 3
     setup_s = time.perf_counter() - T_START
     compiles_before = counter.compiles
 
@@ -198,7 +228,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     compiles_in_window = counter.compiles - compiles_before
     stats = [d.memory_stats() or {} for d in devices]
     peak_bytes = max(s.get("peak_bytes_in_use", 0) for s in stats)
-    reduced = tracer.reduce(task.kernels()) if tracer else None
+    reduced = None
+    if tracer:
+        r0 = time.perf_counter()
+        scopes = task.scopes() if hasattr(task, "scopes") else {}
+        reduced = tracer.reduce(task.kernels(), scopes)
+        log(f"trace reduced in {time.perf_counter() - r0} s; device "
+            f"seconds by scope {reduced['scope_s']}, by kernel "
+            f"{reduced['kernel_s']}")
     log(f"compiles in window: {compiles_in_window}; persistent cache "
         f"hits so far: {counter.cache_hits}")
     reserved = max(s.get("peak_bytes_reserved", 0) for s in stats)
@@ -227,7 +264,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                "flops_per_sample": task.flops_per_sample,
                "epilogue_bytes": task.epilogue_bytes(),
                "peaks": peak_table(devices[0].device_kind),
-               "chips": len(devices), "trace": reduced}
+               "chips": len(devices), "trace": reduced,
+               "work": (task.work(list(range(first, t)))
+                        if hasattr(task, "work") else {}),
+               "scope_s": reduced["scope_s"]}
         for m in cell["per_layer"]:
             value = readers[m["name"]](ctx)
             if value is not None:

@@ -3,9 +3,10 @@ ResNet-18-GN): the system under test, built from a configuration file
 and a traffic file the way ``repro.launch.train.build_vision_task``
 builds it, fed only arrays this benchmark generated.
 
-The harness calls ``build(config, traffic, seed, **plants)`` and drives
-what it returns; everything here that is not the program (data,
-weights, cohorts, the reference) comes from the benchmark's own files.
+The harness loads the traffic file with the fields ``TRAFFIC_FIELDS``
+declares, calls ``build(config, traffic, seed)`` and drives what it
+returns; everything here that is not the program (data, weights,
+cohorts, the reference) comes from the benchmark's own files.
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ import numpy as np
 import fl
 import flops
 import models
+import tracing
 import traffic as traffic_mod
 from traffic import Traffic
 
@@ -29,6 +31,12 @@ from repro.core.samplers import ClientSampler
 from repro.ingest import FederatedImageData, StreamingImageSource
 from repro.models.vision import VisionConfig, vision_loss_fn
 
+# this task's own traffic fields: the images of each class, and the
+# standard deviation of their Gaussian pixel noise
+TRAFFIC_FIELDS = {"samples_per_class": int, "image_noise": float}
+# the round program's stage scopes (repro.trace)
+SCOPES = ("fl.local", "fl.codec", "fl.guard", "fl.server",
+          "fl.server.reduce", "fl.server.epilogue")
 NEVER = 10 ** 9      # rounds horizon and eval cadence: no end, no eval
 # a Mosaic call's serialized kernel, and the epilogue kernel's function
 # name in it (not the dequantizing variant's)
@@ -76,10 +84,11 @@ class VisionTask:
         self.traffic = traffic
         w_img, w_init, self.trainer_seed = traffic_mod.seed_words(seed, 3)
         self.labels, self.parts = traffic_mod.population(
-            traffic, self.model["num_classes"])
+            traffic, self.model["num_classes"],
+            traffic.data["samples_per_class"])
         self.images = traffic_mod.images_for(
             self.labels, self.model["image_size"], self.model["channels"],
-            self.model["num_classes"], traffic.image_noise, w_img)
+            self.model["num_classes"], traffic.data["image_noise"], w_img)
         params = models.init_params(self.model, w_init)
         self.params0 = jax.device_get(params)
         self.num_params = sum(x.size for x in jax.tree.leaves(self.params0))
@@ -197,6 +206,23 @@ class VisionTask:
             return event_name.split(" = ", 1)[0].lstrip("%") in names
 
         return {"batched_epilogue": is_epilogue}
+
+    def scopes(self) -> Dict[str, Any]:
+        """Program scope -> a test on a trace's op event name, for each
+        of the round's stage scopes, from the compiled round's text."""
+        return tracing.scope_tests(self._round_program().as_text(), SCOPES)
+
+    def work(self, rounds: List[int]) -> Dict[str, Dict[str, int]]:
+        """Operations and bytes the listed rounds require, by kernel:
+        the server epilogue's, from shapes (bench/flops.py), where the
+        round takes the epilogue kernel."""
+        per_round = self.epilogue_bytes()
+        if per_round is None:
+            return {}
+        k = self.traffic.clients_per_round
+        return {"batched_epilogue": {
+            "flops": flops.epilogue_flops(self.num_params, k) * len(rounds),
+            "bytes": per_round * len(rounds)}}
 
     def run_round(self, t: int):
         """One round through the program's own entry point; the first

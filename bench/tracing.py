@@ -14,10 +14,18 @@ around the harness's work between rounds. ``reduce`` turns an
   to the last one's end on the host;
 * ``kernel_s``: per named kernel, the summed device time of its events,
   mean over the chips;
+* ``scope_s``: per program scope (a ``jax.named_scope`` of the round
+  program), the union of the intervals of its ops, per chip, inside
+  the window; the mean over the chips. A union and never a sum: a
+  ``while`` op's event covers the events of the ops of its body, and
+  those of a loop nested in it, and each is an op of the scope;
 * ``collective_exposed_s``: per chip, the time collective ops ran while
   no other op did; the mean over the chips;
 * ``breakdown``: the ten device ops that took most time, and the ten
   longest idle gaps, each named by the host span it fell in.
+
+``scope_ops`` finds a scope's ops in the compiled program's text, and
+``scope_tests`` makes a task's ``scopes()`` from them.
 """
 from __future__ import annotations
 
@@ -26,7 +34,7 @@ import re
 import shutil
 from collections import defaultdict
 from pathlib import Path
-from typing import Callable, Dict, Iterable, List, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Set, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
@@ -37,14 +45,99 @@ COLLECTIVE = re.compile(r"\b(all-reduce|all-gather|reduce-scatter|"
                         r"^%?(all-reduce|all-gather|reduce-scatter|"
                         r"collective-permute|all-to-all)")
 TOP = 10
+# HLO text: a computation's header, an instruction, its op_name, and the
+# computations an instruction calls
+COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.-]+)\s.*\{\s*$")
+INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.-]+)\s+=\s")
+OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+CALLS = re.compile(r"\b(?:body|condition|calls|to_apply|true_computation|"
+                   r"false_computation)=%?([\w.-]+)")
+CALL_LISTS = re.compile(r"\b(?:branch_computations|called_computations)="
+                        r"\{([^}]*)\}")
+TRANSFORM = re.compile(r"^[\w.-]*\((.*)\)$")
 
 Interval = Tuple[int, int]
+Tests = Dict[str, Callable[[str], bool]]
 
 
 def op_name(event_name: str) -> str:
     """An XLA op event is named by its HLO instruction text; its name is
     what precedes ' = '."""
     return event_name.split(" = ", 1)[0].lstrip("%")
+
+
+def _in_scope(op_path: str, scope: str) -> bool:
+    """Whether ``scope`` is a component of an op_name path. A component
+    under a transformation, such as ``transpose(jvp(attn))`` in a
+    backward pass, is the scope it wraps."""
+    for part in op_path.split("/"):
+        while part != scope:
+            m = TRANSFORM.match(part)
+            if not m:
+                break
+            part = m.group(1)
+        if part == scope:
+            return True
+    return False
+
+
+def scope_ops(hlo_text: str, scope: str) -> Set[str]:
+    """Names of the instructions of a compiled program's text (HLO,
+    ``compiled.as_text()``) that belong to ``scope``: those whose
+    metadata ``op_name`` has the scope as a path component, and, in a
+    computation that such an instruction calls, at any depth (a ``while``
+    loop's body and condition, a conditional's branches), those that
+    carry no ``op_name`` of their own, as the loop's counter and copies
+    do. An op of another scope that XLA fused into a fusion of this one
+    keeps its own name; only the fusion runs, as one op."""
+    members: Dict[str, List[str]] = defaultdict(list)
+    callees: Dict[str, List[str]] = {}
+    tagged: Set[str] = set()
+    found: Set[str] = set()
+    roots: List[str] = []
+    comp = None
+    for line in hlo_text.splitlines():
+        head = None if line[:1].isspace() else COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = INSTRUCTION.match(line)
+        if not m or comp is None:
+            continue
+        name = m.group(1)
+        members[comp].append(name)
+        called = CALLS.findall(line)
+        for group in CALL_LISTS.findall(line):
+            called += [c.strip().lstrip("%") for c in group.split(",")
+                       if c.strip()]
+        callees[name] = called
+        op = OP_NAME.search(line)
+        if op and op.group(1):
+            tagged.add(name)
+            if _in_scope(op.group(1), scope):
+                found.add(name)
+                roots += called
+    seen: Set[str] = set()
+    while roots:
+        c = roots.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for name in members.get(c, ()):
+            if name not in tagged:
+                found.add(name)
+                roots += callees.get(name, [])
+    return found
+
+
+def scope_tests(hlo_text: str, scopes: Iterable[str]) -> Tests:
+    """Each scope -> a test on a trace's op event name: whether the op
+    is one of the scope's instructions (``scope_ops``)."""
+    out = {}
+    for scope in scopes:
+        names = frozenset(scope_ops(hlo_text, scope))
+        out[scope] = lambda n, names=names: op_name(n) in names
+    return out
 
 
 def union(intervals: Iterable[Interval]) -> List[Interval]:
@@ -90,11 +183,13 @@ def is_collective(event_name: str) -> bool:
     return bool(COLLECTIVE.search(event_name))
 
 
-def reduce(planes, kernels: Dict[str, Callable[[str], bool]]
+def reduce(planes, kernels: Tests, scopes: Optional[Tests] = None
            ) -> Dict[str, object]:
     """``planes``: a ProfileData's planes (or objects with the same
     ``name``/``lines``/``events`` shape). ``kernels`` maps a kernel's
-    name to a test on an op event's name."""
+    name, and ``scopes`` a program scope's, to a test on an op event's
+    name."""
+    scopes = scopes or {}
     spans: Dict[str, List[Interval]] = defaultdict(list)
     device_ops: Dict[int, List[Tuple[str, int, int]]] = {}
     seen: Dict[str, List[str]] = {}
@@ -123,6 +218,7 @@ def reduce(planes, kernels: Dict[str, Callable[[str], bool]]
     chips = len(device_ops)
     busy, exposed = 0, 0
     kernel_ns: Dict[str, int] = {k: 0 for k in kernels}
+    scope_ns: Dict[str, int] = {k: 0 for k in scopes}
     op_ns: Dict[str, int] = defaultdict(int)
     gaps: List[Tuple[int, int]] = []
     for ops in device_ops.values():
@@ -140,12 +236,16 @@ def reduce(planes, kernels: Dict[str, Callable[[str], bool]]
             for k, test in kernels.items():
                 if test(n):
                     kernel_ns[k] += d
+        for k, test in scopes.items():
+            scope_ns[k] += length(union(clip(
+                [(s, e) for n, s, e in inside if test(n)], lo, hi)))
         gaps += subtract([(lo, hi)], both)
     window = hi - lo
     return {
         "busy_s": busy / chips / 1e9, "window_s": window / 1e9,
         "chips": chips,
         "kernel_s": {k: v / chips / 1e9 for k, v in kernel_ns.items()},
+        "scope_s": {k: v / chips / 1e9 for k, v in scope_ns.items()},
         "collective_exposed_s": exposed / chips / 1e9,
         "breakdown": {
             "device_ops": [[n, v / chips / 1e9] for n, v in sorted(
@@ -191,7 +291,7 @@ class Tracer:
     def stop(self):
         self.jax.profiler.stop_trace()
 
-    def reduce(self, kernels: Dict[str, Callable[[str], bool]]
+    def reduce(self, kernels: Tests, scopes: Optional[Tests] = None
                ) -> Dict[str, object]:
         try:
             files = glob.glob(str(self.directory / "**" / "*.xplane.pb"),
@@ -199,6 +299,6 @@ class Tracer:
             if len(files) != 1:
                 raise ValueError(f"expected one trace file, found {files}")
             data = self.jax.profiler.ProfileData.from_file(files[0])
-            return reduce(data.planes, kernels)
+            return reduce(data.planes, kernels, scopes)
         finally:
             shutil.rmtree(self.directory, ignore_errors=True)

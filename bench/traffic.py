@@ -1,14 +1,19 @@
-"""The benchmark's own traffic: a federated image population and the
-cohorts that arrive at the server, made from a traffic file and a seed.
+"""The benchmark's own traffic: a federated population and the cohorts
+that arrive at the server, made from a traffic file and a seed.
 
 A traffic file (``bench/traffic/<name>.json``) holds parameters only;
-this one generator reads every such file. Two seeds are kept apart:
+this one generator reads every such file. The fields every FedDPC cell
+has are ``Traffic``'s own; the fields of a task's data (an image task's
+samples per class and pixel noise, a token task's sequence length) are
+declared by the task module in its ``TRAFFIC_FIELDS`` table and loaded
+into ``Traffic.data``. Two seeds are kept apart:
 
 * ``population_seed`` (in the file) fixes the deployment: the class of
   every sample and the Dirichlet partition of samples over clients. So
   the clients' sizes, and with them the shape bucket M and the useful
   share of the local steps, are the same in every run of a cell.
-* ``--seed`` (per run) draws the image content and the initial weights.
+* ``--seed`` (per run) draws the data's content (pixels, tokens) and
+  the initial weights.
 
 The order in which clients arrive comes from the population seed too
 (``arrivals_rng``): every run of a cell trains the same cohorts in the
@@ -24,16 +29,18 @@ them from the program, so a change there cannot move its yardstick.
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass, fields
+from dataclasses import dataclass, field, fields
 from pathlib import Path
-from typing import List, Tuple
+from types import MappingProxyType
+from typing import Any, List, Mapping, Tuple, get_type_hints
 
 import numpy as np
 
 
 @dataclass(frozen=True)
 class Traffic:
-    """One traffic mix: the federated job a cell runs."""
+    """One traffic mix: the federated job a cell runs. ``data`` holds the
+    task's own fields, as its module's ``TRAFFIC_FIELDS`` declares them."""
     name: str
     num_clients: int
     clients_per_round: int
@@ -41,23 +48,50 @@ class Traffic:
     local_epochs: int
     dirichlet_alpha: float
     population_seed: int
-    samples_per_class: int
-    image_noise: float
     eta_l: float
     eta_g: float
     lam: float
     shard_clients: bool
+    data: Mapping[str, Any] = field(default_factory=dict)
 
     @classmethod
-    def load(cls, path: Path) -> "Traffic":
+    def load(cls, path: Path, task_fields: Mapping[str, type]
+             ) -> "Traffic":
+        """The traffic file at ``path``, strictly: every common field and
+        every field the task declares, each of its declared type, and no
+        other key but ``why`` and ``assumed``."""
         raw = json.loads(Path(path).read_text())
-        want = {f.name for f in fields(cls)} - {"name"}
-        missing = want - raw.keys()
-        extra = raw.keys() - want - {"why", "assumed"}
+        types = get_type_hints(cls)
+        common = {f.name: types[f.name] for f in fields(cls)
+                  if f.name not in ("name", "data")}
+        clash = common.keys() & task_fields.keys()
+        if clash:
+            raise ValueError(f"the task declares common traffic fields "
+                             f"{sorted(clash)}")
+        want = {**common, **task_fields}
+        missing = want.keys() - raw.keys()
+        extra = raw.keys() - want.keys() - {"why", "assumed"}
         if missing or extra:
             raise ValueError(f"{path}: missing {sorted(missing)}, "
                              f"unknown {sorted(extra)}")
-        return cls(name=Path(path).stem, **{k: raw[k] for k in want})
+        values = {k: _typed(path, k, raw[k], t) for k, t in want.items()}
+        return cls(name=Path(path).stem,
+                   data=MappingProxyType({k: values.pop(k)
+                                          for k in task_fields}),
+                   **values)
+
+
+def _typed(path, key: str, value, kind: type):
+    """``value`` as a ``kind``; a whole number stands for a float, a
+    bool for nothing but a bool."""
+    if kind is float and isinstance(value, int) \
+            and not isinstance(value, bool):
+        return float(value)
+    if isinstance(value, bool) != (kind is bool) \
+            or not isinstance(value, kind):
+        raise ValueError(f"{path}: {key} is {value!r}, not of type "
+                         f"{kind.__name__}")
+    return value
 
 
 def seed_words(seed: int, n: int) -> List[int]:
@@ -96,12 +130,14 @@ def dirichlet_partition(labels: np.ndarray, num_clients: int, alpha: float,
     return [np.asarray(sorted(c), dtype=np.int64) for c in client_idx]
 
 
-def population(traffic: Traffic, num_classes: int
+def population(traffic: Traffic, num_classes: int, samples_per_class: int
                ) -> Tuple[np.ndarray, List[np.ndarray]]:
-    """(labels, client index arrays), fixed by the population seed."""
+    """(labels, client index arrays), fixed by the population seed:
+    ``samples_per_class`` samples of each class, shuffled, split over
+    the clients by the Dirichlet rule."""
     rng = np.random.RandomState(traffic.population_seed)
     labels = np.repeat(np.arange(num_classes, dtype=np.int32),
-                       traffic.samples_per_class)
+                       samples_per_class)
     labels = labels[rng.permutation(len(labels))]
     parts = dirichlet_partition(labels, traffic.num_clients,
                                 traffic.dirichlet_alpha,

@@ -26,12 +26,7 @@ def _limits(cell):
 @pytest.fixture(scope="module")
 def tiny_readings(tmp_path_factory):
     root = benchtiny.make_root(tmp_path_factory.mktemp("control"))
-    harness.setup_paths(root)
-    import traffic as traffic_mod
-    cell = harness.find_cell(CELL, root)
-    task = harness.load_module(cell["task_path"], "task_vision").build(
-        cell["config_data"], traffic_mod.Traffic.load(cell["traffic_path"]),
-        2 ** 31 + 3)
+    task = benchtiny.build(root, 2 ** 31 + 3)
     for t in range(3):
         task.run_round(t)
     return dict(calibrate.readings(task))

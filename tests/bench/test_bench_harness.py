@@ -82,12 +82,7 @@ def test_useful_steps_equal_staged_masks(tmp_path):
     the batch rule, equals what the program's ingest pipeline stages as
     masks, round by round."""
     root = benchtiny.make_root(tmp_path, traffic={"dirichlet_alpha": 0.2})
-    harness.setup_paths(root)
-    import traffic as traffic_mod
-    cell = harness.find_cell(CELL, root)
-    task = harness.load_module(cell["task_path"], "task_vision").build(
-        cell["config_data"], traffic_mod.Traffic.load(cell["traffic_path"]),
-        5)
+    task = benchtiny.build(root, 5)
     staged = []
     inner = task.trainer._cohort_round
 
@@ -157,7 +152,8 @@ def test_layer_metric_readers():
            "epilogue_bytes": 1e6, "peaks": peaks, "chips": 1,
            "trace": {"busy_s": 0.5, "window_s": 2.0,
                      "kernel_s": {"batched_epilogue": 0.004},
-                     "collective_exposed_s": 0.0}}
+                     "collective_exposed_s": 0.0},
+           "scope_s": {"fl.local": 0.4, "fl.server": 0.01}}
     assert read["ingest.wait_s"](ctx) == pytest.approx(0.15)
     assert read["local.useful_step_frac"](ctx) == pytest.approx(0.25)
     assert read["device.idle_frac"](ctx) == pytest.approx(0.75)
@@ -165,6 +161,12 @@ def test_layer_metric_readers():
     assert read["round.mfu"](ctx) == pytest.approx(0.05)
     # 1 ms at peak per round, 2 ms spent per round
     assert read["batched_epilogue_roofline"](ctx) == pytest.approx(50.0)
+    # device seconds of a scope, per round of the window
+    assert read["local.device_s"](ctx) == pytest.approx(0.2)
+    assert read["server.device_s"](ctx) == pytest.approx(0.005)
+    ctx["scope_s"] = {"fl.local": 0.0}
+    assert read["local.device_s"](ctx) is None
+    assert read["server.device_s"](ctx) is None
     ctx["trace"]["kernel_s"] = {"batched_epilogue": 0.0}
     assert read["batched_epilogue_roofline"](ctx) is None
     ctx.update(chips=4, epilogue_bytes=None)

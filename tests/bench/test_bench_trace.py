@@ -1,6 +1,7 @@
 """The benchmark's trace reduction (bench/tracing.py), on synthetic traces
 with known intervals."""
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -9,49 +10,8 @@ import pytest
 HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE.parents[1] / "bench"))
 
+import benchtiny  # noqa: E402
 import tracing  # noqa: E402
-
-NS = 1000   # picoseconds per nanosecond
-
-
-def _xspace(device_ops, host_spans):
-    """A text-proto XSpace: ``device_ops`` maps a chip index to
-    (name, start_ns, end_ns) op events; ``host_spans`` is a list of
-    (name, start_ns, end_ns) on one host thread."""
-    meta, ids = [], {}
-
-    def mid(name):
-        if name not in ids:
-            ids[name] = len(ids) + 1
-            meta.append(name)
-        return ids[name]
-
-    def events(evs):
-        return "\n".join(
-            f"events {{ metadata_id: {mid(n)} offset_ps: {s * NS} "
-            f"duration_ps: {(e - s) * NS} }}" for n, s, e in evs)
-
-    def metadata():
-        return "\n".join(
-            f'event_metadata {{ key: {ids[n]} value {{ id: {ids[n]} '
-            f'name: {json.dumps(n)} }} }}' for n in meta)
-
-    planes = []
-    for chip, ops in device_ops.items():
-        ids.clear()
-        meta.clear()
-        body = events(ops)
-        planes.append(f'planes {{ id: {chip + 1} name: "/device:TPU:{chip}" '
-                      f'lines {{ id: 1 name: "XLA Ops" timestamp_ns: 0 '
-                      f'{body} }} {metadata()} }}')
-    ids.clear()
-    meta.clear()
-    body = events(host_spans)
-    planes.append(f'planes {{ id: 99 name: "/host:CPU" lines {{ id: 1 '
-                  f'name: "python3" timestamp_ns: 0 {body} }} '
-                  f'{metadata()} }}')
-    import jax
-    return jax.profiler.ProfileData.from_text_proto("\n".join(planes))
 
 
 def test_interval_arithmetic():
@@ -77,7 +37,7 @@ def test_synthetic_trace_known_intervals():
              ("%all-gather.5 = f32[] all-gather()", 300, 500)]
     host = [("bench.round", 100, 600), ("bench.between", 600, 610),
             ("bench.round", 610, 1100), ("bench.between", 1100, 1105)]
-    data = _xspace({0: chip0, 1: chip1}, host)
+    data = benchtiny.xspace({0: chip0, 1: chip1}, host)
     got = tracing.reduce(data.planes, {
         "batched_epilogue": lambda n: n.startswith("%custom-call.7 ")})
     assert got["chips"] == 2
@@ -96,8 +56,98 @@ def test_synthetic_trace_known_intervals():
     assert len(gaps) <= tracing.TOP
 
 
+def test_scope_time_is_a_union_not_a_sum():
+    """A ``while`` op's event covers the events of its body and of a
+    loop nested in it, all ops of one scope: the scope's time is their
+    union (the summed times would count the loop three times). An op
+    outside the scope is not counted, and the window clips."""
+    local = {"while.343", "while.344", "fusion.7", "copy.2"}
+    chip0 = [("%while.343 = (f32[]) while(%t), body=%b", 100, 700),
+             ("%while.344 = (f32[]) while(%u), body=%c", 150, 650),
+             ("%fusion.7 = f32[] fusion(%a), kind=kOutput", 200, 300),
+             ("%copy.2 = f32[] copy(%a)", 690, 720),       # past the while
+             ("%fusion.9 = f32[] fusion(%w), kind=kLoop", 730, 760),
+             ("%while.343 = (f32[]) while(%t), body=%b", 900, 1300)]
+    chip1 = [("%while.343 = (f32[]) while(%t), body=%b", 100, 300),
+             ("%fusion.9 = f32[] fusion(%w), kind=kLoop", 300, 400)]
+    host = [("bench.round", 100, 800), ("bench.round", 850, 1100)]
+    got = tracing.reduce(
+        benchtiny.xspace({0: chip0, 1: chip1}, host).planes, {},
+        {"fl.local": lambda n: tracing.op_name(n) in local,
+         "fl.server": lambda n: tracing.op_name(n) == "fusion.9",
+         "fl.guard": lambda n: False})
+    # chip 0: [100, 720) + [900, 1100) clipped = 820; chip 1: 200
+    assert got["scope_s"]["fl.local"] == pytest.approx((820 + 200) / 2e9)
+    assert got["scope_s"]["fl.server"] == pytest.approx((30 + 100) / 2e9)
+    assert got["scope_s"]["fl.guard"] == 0.0
+    ops = dict(got["breakdown"]["device_ops"])     # the breakdown sums
+    assert ops["while.343"] == pytest.approx((600 + 200 + 200) / 2e9)
+
+
+def test_scope_ops_from_compiled_text():
+    """On the CPU, a jitted function with nested ``jax.named_scope``s, a
+    scan (a ``while`` loop) and a gradient inside a scope: each scope's
+    instructions are found in the compiled text, the loop's body and
+    condition with the loop, the backward pass's ops of a scope under
+    ``jvp(...)``/``transpose(...)`` with it, and nothing of one scope in
+    a scope beside it."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(w, x):
+        with jax.named_scope("attn"):
+            h = jnp.tanh(x @ w)
+        return (h ** 2).sum()
+
+    def f(w, xs):
+        with jax.named_scope("fl.local"):
+            def body(c, x):
+                return c - 0.1 * jax.grad(loss)(c, x), None
+            w2, _ = jax.lax.scan(body, w, xs)
+        with jax.named_scope("fl.server"):
+            with jax.named_scope("fl.server.reduce"):
+                s = (w2 * w2).sum()
+            with jax.named_scope("fl.server.epilogue"):
+                return jnp.exp(w2) / s
+
+    text = jax.jit(f).lower(jnp.ones((4, 4)),
+                            jnp.ones((3, 2, 4))).compile().as_text()
+    lines = {tracing.op_name(line.strip().removeprefix("ROOT ")): line
+             for line in text.splitlines() if " = " in line
+             and line[:1].isspace()}
+    scopes = {k: tracing.scope_ops(text, k) for k in (
+        "fl.local", "attn", "fl.server", "fl.server.reduce",
+        "fl.server.epilogue", "fl.codec")}
+    loops = [n for n, line in lines.items() if " while(" in line]
+    assert loops and set(loops) <= scopes["fl.local"]
+    for loop in loops:           # its body and condition, whole
+        for comp in re.findall(r"(?:body|condition)=%?([\w.-]+)",
+                               lines[loop]):
+            assert _members(text, comp) <= scopes["fl.local"], comp
+    assert scopes["attn"] and scopes["attn"] <= scopes["fl.local"]
+    assert any("transpose(jvp(attn))" in lines[n] for n in scopes["attn"])
+    assert scopes["fl.server.reduce"] and scopes["fl.server.epilogue"]
+    assert scopes["fl.server.reduce"] | scopes["fl.server.epilogue"] \
+        <= scopes["fl.server"]
+    assert not scopes["fl.server.reduce"] & scopes["fl.server.epilogue"]
+    assert not scopes["fl.local"] & scopes["fl.server"]
+    assert scopes["fl.codec"] == set()
+
+
+def _members(text, computation):
+    """The instruction names of one computation of an HLO text."""
+    out, inside = set(), False
+    for line in text.splitlines():
+        if not line[:1].isspace():
+            inside = re.match(rf"^(?:ENTRY\s+)?%?{re.escape(computation)}"
+                              rf"\s", line) is not None
+        elif inside and " = " in line:
+            out.add(tracing.op_name(line.strip().removeprefix("ROOT ")))
+    return out
+
+
 def test_no_device_ops_is_an_error():
-    data = _xspace({}, [("bench.round", 0, 10)])
+    data = benchtiny.xspace({}, [("bench.round", 0, 10)])
     with pytest.raises(ValueError, match="no TPU op events"):
         tracing.reduce(data.planes, {})
 

@@ -7,7 +7,7 @@ from types import SimpleNamespace as R
 import pytest
 
 import benchtiny
-from benchtiny import CELL, REPO
+from benchtiny import REPO
 
 import run as harness  # noqa: E402  (bench/, put on the path by benchtiny)
 
@@ -71,12 +71,7 @@ def test_program_step_counts_equal_the_benchmark_count(tmp_path):
     of the batch rule, round by round: local.useful_step_frac may be
     read from either."""
     root = benchtiny.make_root(tmp_path, traffic={"dirichlet_alpha": 0.2})
-    harness.setup_paths(root)
-    import traffic as traffic_mod
-    cell = harness.find_cell(CELL, root)
-    task = harness.load_module(cell["task_path"], "task_vision_steps").build(
-        cell["config_data"], traffic_mod.Traffic.load(cell["traffic_path"]),
-        2 ** 31 + 5)
+    task = benchtiny.build(root, 2 ** 31 + 5)
     try:
         recs = [task.run_round(t) for t in range(6)]
     finally:

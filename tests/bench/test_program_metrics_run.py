@@ -61,12 +61,7 @@ def test_step_and_byte_readers_on_a_tiny_run(tmp_path):
     steps leave fewer padded client-steps, and the round places its
     sample indices, mask and ids, not pixels."""
     root = benchtiny.make_root(tmp_path, traffic={"dirichlet_alpha": 0.2})
-    harness.setup_paths(root)
-    import traffic as traffic_mod
-    cell = harness.find_cell(CELL, root)
-    task = harness.load_module(cell["task_path"], "task_vision_run").build(
-        cell["config_data"], traffic_mod.Traffic.load(cell["traffic_path"]),
-        2 ** 31 + 7)
+    task = benchtiny.build(root, 2 ** 31 + 7)
     try:
         recs = [task.run_round(t) for t in range(6)]
     finally:
